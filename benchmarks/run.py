@@ -217,6 +217,9 @@ def main(argv=None) -> int:
                     help="regression threshold as a fraction (default 0.20)")
     args = ap.parse_args(argv)
     fast, smoke = args.fast, args.smoke
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     if args.compare is not None and not (fast or smoke):
         return _do_compare(args, None)  # compare-only: no benchmark run

@@ -1,154 +1,31 @@
-"""jax API compatibility shims for the sharding-aware layers.
+"""Mesh helpers for the sharding-aware layers.
 
-The mesh-axis-type API moved across jax releases: ``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=...)`` and ``jax.sharding.get_abstract_mesh``
-exist on current jax but not on the 0.4.x line (where the abstract-mesh
-helpers live under ``jax._src.mesh`` and meshes have no axis types at all).
-Every call site resolves the API through this module so the models, trainer
-and serving engine run on both: with axis types, sharding constraints are
-restricted to the Auto (GSPMD-controlled) axes; without them, every mesh
-axis is treated as Auto — correct on 0.4.x, where partial-manual shard_map
-axis types don't exist either.
-
-Beyond resolver functions, :func:`install` *backfills* the small set of
-current-jax public entry points the trainer and its tests call directly —
-``jax.set_mesh``, ``jax.shard_map``, ``jax.sharding.AxisType``, the
-two-argument ``jax.sharding.AbstractMesh(sizes, names)`` constructor and the
-``axis_types=`` kwarg of ``jax.make_mesh`` — as thin adapters over their
-0.4.x equivalents.  Each polyfill is a no-op when the real API exists, so
-the same code (and the same test files) runs on both lines.  ``install()``
-runs at import of this module; everything under ``repro`` imports it before
-touching meshes.
+Meshes in this repo are GSPMD-controlled: every axis is ``AxisType.Auto``
+(``jax.make_mesh`` defaults to Explicit).  Inside a partial-manual
+``jax.shard_map`` the manual axes must not appear in sharding constraints,
+so the models, trainer and serving engine ask :func:`auto_axis_names` which
+axes they may still pin.
 """
 
 from __future__ import annotations
 
-import contextlib
-import enum
-import functools
-
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates axis_types
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD-controlled)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def get_abstract_mesh():
-    """The current abstract mesh, or None when unavailable or empty."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        try:
-            from jax._src import mesh as _mesh_lib
-
-            fn = _mesh_lib.get_abstract_mesh
-        except (ImportError, AttributeError):
-            return None
-    try:
-        m = fn()
-    except Exception:
-        return None
-    if m is None or not getattr(m, "axis_names", None):
-        return None
-    return m
+    """The current abstract mesh, or None outside any mesh context."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def auto_axis_names(mesh) -> set:
-    """Names of mesh axes still under GSPMD (Auto) control.
-
-    Inside a partial-manual shard_map the Manual axes must not appear in
-    sharding constraints; on jax without axis types there is no partial-
-    manual mode, so every axis is Auto.
-    """
+    """Names of mesh axes still under GSPMD (Auto) control."""
     if mesh is None:
         return set()
-    names = tuple(mesh.axis_names)
-    types = getattr(mesh, "axis_types", None)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if types is None or axis_type is None:
-        return set(names)
-    return {n for n, t in zip(names, types) if t == axis_type.Auto}
-
-
-# --------------------------------------------------------------------------
-# Polyfills: backfill current-jax public APIs on the 0.4.x line.
-# --------------------------------------------------------------------------
-
-
-class _AxisType(enum.Enum):
-    """Stand-in for ``jax.sharding.AxisType``.  0.4.x meshes carry no axis
-    types, so every axis behaves as Auto; the enum exists only so code and
-    tests written against current jax parse and run."""
-
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-def _wrap_make_mesh(orig):
-    @functools.wraps(orig)
-    def make_mesh_compat(axis_shapes, axis_names, *args, **kwargs):
-        kwargs.pop("axis_types", None)  # 0.4.x meshes are untyped (all Auto)
-        return orig(axis_shapes, axis_names, *args, **kwargs)
-
-    return make_mesh_compat
-
-
-def _wrap_abstract_mesh(orig):
-    @functools.wraps(orig, updated=())
-    def abstract_mesh_compat(axis_sizes, axis_names=None, **kwargs):
-        kwargs.pop("axis_types", None)
-        if axis_names is None:  # old-style ((name, size), ...) single arg
-            return orig(axis_sizes, **kwargs)
-        return orig(tuple(zip(axis_names, axis_sizes)), **kwargs)
-
-    return abstract_mesh_compat
-
-
-@contextlib.contextmanager
-def _set_mesh(mesh):
-    """``jax.set_mesh`` fallback: enter the legacy Mesh context.  Code in
-    this repo passes meshes explicitly via NamedSharding, so the context
-    only needs to make the mesh ambient for axis-name resolution."""
-    with mesh:
-        yield mesh
-
-
-def _shard_map_compat(f, *, mesh, in_specs, out_specs,
-                      axis_names=None, check_vma=None, **kwargs):
-    """Adapter: current-jax ``jax.shard_map(axis_names=, check_vma=)`` on
-    top of 0.4.x ``jax.experimental.shard_map(auto=, check_rep=)``.  The new
-    API names the *manual* axes; the old one names the complement."""
-    from jax.experimental.shard_map import shard_map as _exp
-
-    manual = frozenset(axis_names) if axis_names else frozenset(mesh.axis_names)
-    auto = frozenset(mesh.axis_names) - manual
-    check_rep = kwargs.pop("check_rep", check_vma)
-    return _exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                auto=auto,
-                check_rep=bool(check_rep) if check_rep is not None else False,
-                **kwargs)
-
-
-def install() -> None:
-    """Backfill missing current-jax APIs onto the jax namespace (idempotent,
-    no-op where the real API exists)."""
-    if getattr(jax.sharding, "AxisType", None) is None:
-        jax.sharding.AxisType = _AxisType
-        jax.make_mesh = _wrap_make_mesh(jax.make_mesh)
-        jax.sharding.AbstractMesh = _wrap_abstract_mesh(jax.sharding.AbstractMesh)
-    if getattr(jax, "set_mesh", None) is None:
-        jax.set_mesh = _set_mesh
-    if getattr(jax, "shard_map", None) is None:
-        jax.shard_map = _shard_map_compat
-
-
-install()
+    return {n for n, t in zip(mesh.axis_names, mesh.axis_types) if t == AxisType.Auto}

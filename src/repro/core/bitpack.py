@@ -97,6 +97,14 @@ def code_mask(w: jax.Array) -> jax.Array:
     return jnp.where(w == 0, jnp.uint32(0), jnp.uint32(0xFFFFFFFF) >> shift)
 
 
+def or_sum(v: jax.Array, axis: int) -> jax.Array:
+    """OR-reduce (keepdims) uint32 values whose set bits never collide, as
+    an int32 sum: the Pallas TPU lowering reduces signed integers only, and
+    disjoint bits add without carries, so the sum *is* the OR."""
+    s = jnp.sum(jax.lax.bitcast_convert_type(v, jnp.int32), axis=axis, keepdims=True)
+    return jax.lax.bitcast_convert_type(s, jnp.uint32)
+
+
 def _block_layout(n: int, block: int) -> tuple[int, int]:
     n_blocks = -(-n // block)
     padded = n_blocks * block

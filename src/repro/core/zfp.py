@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import bitpack
+
 Q = 25  # fixed-point fractional bits; transform growth (< 2^3) keeps int32 safe
 _NBMASK_VAL = 0xAAAAAAAA  # python int: jnp scalars are built per-call so the
 # negabinary helpers stay usable inside Pallas bodies (a module-level device
@@ -113,9 +115,8 @@ class ZFPCompressed:
     rate: int  # static bits/value
 
 
-def fwd_lift(v: jax.Array) -> jax.Array:
-    """ZFP forward lift along the last axis (length 4), exact int32."""
-    x, y, z, w = v[..., 0], v[..., 1], v[..., 2], v[..., 3]
+def _fwd_lift4(x, y, z, w):
+    """ZFP fwd_lift on the four lanes of a length-4 transform axis."""
     x = x + w
     x = x >> 1
     w = w - x
@@ -130,12 +131,11 @@ def fwd_lift(v: jax.Array) -> jax.Array:
     y = y - w
     w = w + (y >> 1)
     y = y - (w >> 1)
-    return jnp.stack([x, y, z, w], axis=-1)
+    return x, y, z, w
 
 
-def inv_lift(v: jax.Array) -> jax.Array:
-    """Exact inverse of :func:`fwd_lift` (ZFP inv_lift)."""
-    x, y, z, w = v[..., 0], v[..., 1], v[..., 2], v[..., 3]
+def _inv_lift4(x, y, z, w):
+    """Exact inverse of :func:`_fwd_lift4` (ZFP inv_lift)."""
     y = y + (w >> 1)
     w = w - (y >> 1)
     y = y + w
@@ -150,10 +150,22 @@ def inv_lift(v: jax.Array) -> jax.Array:
     w = w + x
     x = x << 1
     x = x - w
-    return jnp.stack([x, y, z, w], axis=-1)
+    return x, y, z, w
+
+
+def fwd_lift(v: jax.Array) -> jax.Array:
+    """ZFP forward lift along the last axis (length 4), exact int32."""
+    return jnp.stack(_fwd_lift4(v[..., 0], v[..., 1], v[..., 2], v[..., 3]), axis=-1)
+
+
+def inv_lift(v: jax.Array) -> jax.Array:
+    """Exact inverse of :func:`fwd_lift` (ZFP inv_lift)."""
+    return jnp.stack(_inv_lift4(v[..., 0], v[..., 1], v[..., 2], v[..., 3]), axis=-1)
 
 
 def _lift3d(blocks: jax.Array) -> jax.Array:
+    """Reference 3-D lift on (n, 4, 4, 4) blocks (the coder runs the same
+    arithmetic coefficient-major, :func:`_fwd_lift_cm`)."""
     b = blocks
     for axis in (3, 2, 1):
         b = jnp.moveaxis(fwd_lift(jnp.moveaxis(b, axis, -1)), -1, axis)
@@ -198,43 +210,157 @@ def _bitlength32(u: jax.Array) -> jax.Array:
 
 def _carve_blocks(x: jax.Array) -> jax.Array:
     """(X,Y,Z) -> (n_blocks, 4, 4, 4) with edge padding (ZFP pads blocks)."""
-    pads = [(0, (-s) % 4) for s in x.shape]
-    xp = jnp.pad(x, pads, mode="edge")
-    gx, gy, gz = (s // 4 for s in xp.shape)
-    xb = xp.reshape(gx, 4, gy, 4, gz, 4).transpose(0, 2, 4, 1, 3, 5)
-    return xb.reshape(-1, 4, 4, 4)
+    return _carve_cm(x).T.reshape(-1, 4, 4, 4)
 
 
 def _uncarve_blocks(xb: jax.Array, shape) -> jax.Array:
+    return _uncarve_cm(xb.reshape(-1, 64).T, shape)
+
+
+# ------------------------------------------ coefficient-major layout -----
+#
+# Every coder stage below runs *coefficient-major* (CM): the 64 coefficients
+# of a block (or its 32 bit planes) lie along axis 0 and the blocks along
+# axis 1.  On the TPU that puts blocks on the 128 vector lanes, so per-block
+# header arithmetic is lane-dense, and every reordering of coefficients is a
+# static row (sublane) permutation or roll — the Pallas kernels
+# (``repro.kernels.zfp_fused``) trace these same functions in VMEM, so the
+# core, xla and fused paths emit identical streams by construction.  Row
+# ``r = 16a + 4b + c`` of a block holds its value at in-block coordinates
+# (a, b, c) of the field's axes (0, 1, 2): "index order".
+
+
+def _carve_cm(x: jax.Array) -> jax.Array:
+    """(X,Y,Z) field -> (64, n_blocks) CM index-order blocks (edge padded).
+
+    Row ``16a + 4b + c`` is the stride-4 sub-lattice ``x[a::4, b::4, c::4]``
+    — 64 strided slices.  (A reshape to ``(..., Z/4, 4)`` + transpose would
+    materialize arrays whose minor dimension is 4, which the TPU pads to a
+    128-lane tile: 32x the field, more than a chip holds at 512^3.)"""
+    pads = [(0, (-s) % 4) for s in x.shape]
+    xp = jnp.pad(x, pads, mode="edge")
+    return jnp.stack([xp[r // 16::4, (r // 4) % 4::4, r % 4::4].reshape(-1)
+                      for r in range(64)])
+
+
+def _uncarve_cm(b: jax.Array, shape) -> jax.Array:
+    """Inverse of :func:`_carve_cm` (strided stores; crops the edge padding)."""
     padded = tuple(s + ((-s) % 4) for s in shape)
-    gx, gy, gz = (s // 4 for s in padded)
-    xp = xb.reshape(gx, gy, gz, 4, 4, 4).transpose(0, 3, 1, 4, 2, 5).reshape(padded)
+    grid = tuple(s // 4 for s in padded)
+    xp = jnp.zeros(padded, b.dtype)
+    for r in range(64):
+        xp = xp.at[r // 16::4, (r // 4) % 4::4, r % 4::4].set(b[r].reshape(grid))
     return xp[tuple(slice(0, s) for s in shape)]
+
+
+_IDENTITY = tuple(range(64))
+
+
+def _digit_order(stride: int) -> tuple[int, ...]:
+    """Rows grouped by their digit along the lift axis of ``stride``: the
+    four 16-row quarters are that axis's x, y, z, w lanes, aligned row for
+    row (stable sort keeps the other two coordinates in the same order)."""
+    return tuple(sorted(_IDENTITY, key=lambda r: (r // stride) % 4))
+
+
+_LIFT_ORDER = {s: _digit_order(s) for s in (1, 4, 16)}  # [16] is identity
+
+
+def _reorder(a: jax.Array, order, want) -> jax.Array:
+    """Static row permutation: ``a``'s row ``i`` holds coefficient
+    ``order[i]``; return the rows of coefficients ``want`` (contiguous runs
+    become one slice each, so this lowers to sublane copies, not a gather)."""
+    order, want = tuple(int(r) for r in order), tuple(int(r) for r in want)
+    if order == want:
+        return a
+    pos = {r: i for i, r in enumerate(order)}
+    parts, i = [], 0
+    while i < len(want):
+        j = i + 1
+        while j < len(want) and pos[want[j]] == pos[want[j - 1]] + 1:
+            j += 1
+        parts.append(a[pos[want[i]]:pos[want[i]] + (j - i)])
+        i = j
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _lift_quarters(a: jax.Array, step) -> jax.Array:
+    q = a.shape[0] // 4
+    return jnp.concatenate(step(*(a[k * q:(k + 1) * q] for k in range(4))), axis=0)
+
+
+def _fwd_lift_cm(a: jax.Array, out_order=_IDENTITY) -> jax.Array:
+    """:func:`_lift3d` on CM index-order rows; returns rows in ``out_order``
+    (``PERM`` lands the coefficients directly in sequency order)."""
+    order = _IDENTITY
+    for stride in (1, 4, 16):  # block axes 2, 1, 0 — as _lift3d's 3, 2, 1
+        a = _reorder(a, order, _LIFT_ORDER[stride])
+        order = _LIFT_ORDER[stride]
+        a = _lift_quarters(a, _fwd_lift4)
+    return _reorder(a, order, out_order)
+
+
+def _inv_lift_cm(a: jax.Array, in_order=_IDENTITY) -> jax.Array:
+    """Inverse of :func:`_fwd_lift_cm`: rows held in ``in_order`` -> CM
+    index-order rows."""
+    order = in_order
+    for stride in (16, 4, 1):
+        a = _reorder(a, order, _LIFT_ORDER[stride])
+        order = _LIFT_ORDER[stride]
+        a = _lift_quarters(a, _inv_lift4)
+    return _reorder(a, order, _IDENTITY)
+
+
+def _transform_cm(b: jax.Array, order=PERM):
+    """Stages 1-4 on CM index-order f32 blocks (64, T): -> (u uint32[64, T]
+    negabinary coefficients with rows in ``order``, emax int32[1, T] (biased
+    exponent, 0 = all-zero block), gtops int32[10, T]).
+
+    The block exponent comes from the IEEE exponent bits of max|x| and the
+    scale 2^(Q - e) is built in exponent bits — exact and branch-free.  After
+    the clip to [-100, 127] the exponent equals ``frexp``'s (they differ only
+    for subnormal maxima, which both clip to -100)."""
+    maxabs = jnp.max(jnp.abs(b), axis=0, keepdims=True)  # (1, T)
+    bits = jax.lax.bitcast_convert_type(maxabs, jnp.uint32)
+    e_biased = ((bits >> 23) & jnp.uint32(0xFF)).astype(jnp.int32)
+    e = jnp.clip(e_biased - 126, -100, 127)  # frexp convention: maxabs < 2^e
+    nonzero = maxabs > 0.0
+    ints = jnp.round(b * exact_exp2(Q - e)).astype(jnp.int32)
+    u = negabinary(_fwd_lift_cm(ints, order))
+    lens = _reorder(_bitlength32(u), order, PERM)  # groups = static row runs
+    gtops = jnp.concatenate(
+        [jnp.max(lens[s0:s0 + int(sz)], axis=0, keepdims=True)
+         for s0, sz in zip(_FIXED_START, GROUP_SIZES)], axis=0)
+    gtops = gtops * nonzero.astype(jnp.int32)
+    emax = jnp.where(nonzero, e + _EMAX_BIAS, 0)
+    return u, emax, gtops
+
+
+def _inverse_cm(u: jax.Array, emax: jax.Array, order=PERM) -> jax.Array:
+    """Invert stages 1-4: negabinary coefficients (rows in ``order``) +
+    emax int32[1, T] -> CM index-order f32 blocks (64, T)."""
+    ints = _inv_lift_cm(inv_negabinary(u), order)
+    e = emax - _EMAX_BIAS
+    scale = jnp.where(emax > 0, exact_exp2(e - Q), 0.0)
+    return ints.astype(jnp.float32) * scale
 
 
 def block_transform(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Stages 1-4: float blocks -> (negabinary sequency coeffs, emax, gtops)."""
-    return blocks_transform(_carve_blocks(x.astype(jnp.float32)))
+    return _transform_rows(_carve_cm(x.astype(jnp.float32)))
 
 
 def blocks_transform(blocks: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Stages 2-4 on already-carved (n, 4, 4, 4) blocks — the entry point
     the arena path batches over (the concatenated blocks of many leaves are
-    just more rows; per-block outputs are independent)."""
-    maxabs = jnp.max(jnp.abs(blocks), axis=(1, 2, 3))
-    _, e = jnp.frexp(maxabs)  # maxabs < 2^e
-    e = jnp.clip(e, -100, 127).astype(jnp.int32)
-    nonzero = maxabs > 0.0
-    scale = exact_exp2(Q - e)
-    ints = jnp.round(blocks * scale[:, None, None, None]).astype(jnp.int32)
-    coef = _lift3d(ints)
-    u = negabinary(coef.reshape(-1, 64))[:, PERM]
-    lens = _bitlength32(u)  # (n, 64)
-    gtops = jnp.zeros((u.shape[0], N_GROUPS), jnp.int32)
-    gtops = gtops.at[:, GROUP_OF_COEF].max(lens)
-    gtops = jnp.where(nonzero[:, None], gtops, 0)
-    emax = jnp.where(nonzero, (e + _EMAX_BIAS), 0).astype(jnp.uint8)
-    return u, emax, gtops
+    just more rows; per-block outputs are independent).  Returns
+    (u uint32[n, 64] sequency order, emax uint8[n], gtops int32[n, 10])."""
+    return _transform_rows(blocks.reshape(-1, 64).T)
+
+
+def _transform_rows(cm: jax.Array):
+    u, emax, gtops = _transform_cm(cm)
+    return u.T, emax[0].astype(jnp.uint8), gtops.T
 
 
 def _schedule_offsets(gtops: jax.Array) -> jax.Array:
@@ -266,6 +392,7 @@ def _schedule_offsets(gtops: jax.Array) -> jax.Array:
 # ``pw[j] = sum_g w[j, g] <= 64`` bits, with group g's run at within-plane
 # offset ``woff[j, g]``.  Every quantity is a pure function of the gtops
 # header, so encoder and decoder derive identical layouts (DESIGN.md §3).
+# All arrays are CM: stream-major plane j is row j of a (32, T) array.
 
 
 def _code_mask(w: jax.Array) -> jax.Array:
@@ -275,7 +402,13 @@ def _code_mask(w: jax.Array) -> jax.Array:
     return jnp.where(w == 0, jnp.uint32(0), jnp.uint32(0xFFFFFFFF) >> shift)
 
 
-def _plane_offsets(gtops: jax.Array, budget: int):
+def _plane_rows() -> jax.Array:
+    """Stream-major plane index j as a (32, 1) column (iota: no captured
+    constant, so Pallas bodies can call it)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (32, 1), 0)
+
+
+def _plane_offsets_cm(gtops: jax.Array, budget: int):
     """Header-derived plane placement, in closed form (no prefix scans).
 
     Group g is present in stream-major plane j (bit plane p = 31 - j) iff
@@ -284,14 +417,14 @@ def _plane_offsets(gtops: jax.Array, budget: int):
     sizes over groups therefore gives both the plane's global exclusive bit
     offset and its payload width without any cumulative scan:
 
-    OFF   int32[n, 32]  global exclusive bit offset of plane j's payload
-    keep  int32[n, 32]  payload bits surviving the ``budget`` truncation
+    OFF   int32[32, T]  global exclusive bit offset of plane j's payload
+    keep  int32[32, T]  payload bits surviving the ``budget`` truncation
     """
-    j = jnp.arange(32, dtype=jnp.int32)[None, :]
-    off = jnp.zeros_like(j)
-    pw = jnp.zeros_like(j)
+    j = _plane_rows()
+    off = jnp.zeros((32, gtops.shape[1]), jnp.int32)
+    pw = jnp.zeros_like(off)
     for g in range(N_GROUPS):
-        t = gtops[:, g][:, None] + j - 32  # (n, 32)
+        t = gtops[g:g + 1] + j - 32  # (32, T)
         sz = int(GROUP_SIZES[g])
         off = off + sz * jnp.maximum(t, 0)
         pw = pw + sz * (t >= 0).astype(jnp.int32)
@@ -299,33 +432,46 @@ def _plane_offsets(gtops: jax.Array, budget: int):
     return off, keep
 
 
+def _plane_offsets(gtops: jax.Array, budget: int):
+    """Block-major view of :func:`_plane_offsets_cm`: int32[n, 32] each."""
+    off, keep = _plane_offsets_cm(gtops.astype(jnp.int32).T, budget)
+    return off.T, keep.T
+
+
 def _mask64(keep: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(lo, hi) uint32 masks keeping the low ``keep`` bits of a 64-bit field."""
     return _code_mask(jnp.minimum(keep, 32)), _code_mask(jnp.clip(keep - 32, 0, 32))
 
 
-def _bit_transpose32(a: jax.Array) -> jax.Array:
-    """Vectorized 32x32 bit-matrix transpose (Hacker's Delight 7-3).
+# (row distance j, mask of the bits whose index has bit j clear)
+_TRANSPOSE_STAGES = ((16, 0x0000FFFF), (8, 0x00FF00FF), (4, 0x0F0F0F0F),
+                     (2, 0x33333333), (1, 0x55555555))
 
-    ``a``: uint32[n, 32] — 32 row words per block.  Returns ``b`` with
-    ``b[:, c] bit k == a[:, 31 - k] bit (31 - c)`` (the algorithm's native
-    anti-diagonal orientation; callers absorb it with a row flip).  Five
-    mask-and-swap stages over (n, 16) halves — O(n log 32) VPU work, the
-    step that turns the 32-pass plane loop into straight word arithmetic.
+
+def _bit_transpose32(a: jax.Array, inverse: bool = False) -> jax.Array:
+    """Coefficient words <-> plane words: a 32x32 bit-matrix transpose over
+    axis 0 of ``a`` uint32[32, T].
+
+    Forward: ``out[j] bit c == a[c] bit (31 - j)`` — row j is stream-major
+    bit plane ``31 - j`` of the 32 coefficient rows, LSB = coefficient 0.
+    Five butterfly stages (Hacker's Delight 7-3); stage j pairs rows r and
+    r ^ j with a roll along axis 0 and swaps bit j of the row index with
+    bit j of the bit index, *plus* the row flip that the plane order needs —
+    so the plane reversal is folded into the network and no reversed slice
+    is ever taken.  ``inverse=True`` runs the inverse stages.
     """
-    n = a.shape[0]
-    m = jnp.uint32(0x0000FFFF)
-    j = 16
-    while j:
-        r = a.reshape(n, 32 // (2 * j), 2, j)
-        lo, hi = r[:, :, 0, :], r[:, :, 1, :]
-        t = (lo ^ (hi >> jnp.uint32(j))) & m
-        lo = lo ^ t
-        hi = hi ^ (t << jnp.uint32(j))
-        a = jnp.stack([lo, hi], axis=2).reshape(n, 32)
-        j >>= 1
-        if j:
-            m = m ^ (m << jnp.uint32(j))
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    for j, mask in _TRANSPOSE_STAGES:
+        m = jnp.uint32(mask)
+        nm = jnp.uint32(~mask & 0xFFFFFFFF)
+        sj = jnp.uint32(j)
+        up = jnp.roll(a, -j, axis=0)  # row r sees row r + j
+        dn = jnp.roll(a, j, axis=0)  # row r sees row r - j
+        low = (row & j) == 0
+        if inverse:
+            a = jnp.where(low, (up & m) | ((a << sj) & nm), ((a >> sj) & m) | (dn & nm))
+        else:
+            a = jnp.where(low, ((a >> sj) & m) | (up & nm), (dn & m) | ((a << sj) & nm))
     return a
 
 
@@ -340,44 +486,40 @@ def _plane_words(u: jax.Array) -> tuple[jax.Array, jax.Array]:
     """uint32[n, 64] sequency coefficients -> (W0, W1) uint32[n, 32]:
     ``W0[:, j] bit c`` = bit plane ``31 - j`` (stream-major) of coefficient
     ``c``; W1 likewise for coefficients 32..63."""
-    w0 = _bit_transpose32(u[:, 31::-1])
-    w1 = _bit_transpose32(u[:, :31:-1])
-    return w0, w1
+    return _bit_transpose32(u[:, :32].T).T, _bit_transpose32(u[:, 32:].T).T
 
 
 def _coef_words(w0: jax.Array, w1: jax.Array) -> jax.Array:
-    """Inverse of :func:`_plane_words` (the transpose is an involution)."""
-    return jnp.concatenate(
-        [_bit_transpose32(w0)[:, ::-1], _bit_transpose32(w1)[:, ::-1]], axis=1
-    )
+    """Inverse of :func:`_plane_words`."""
+    return jnp.concatenate([_bit_transpose32(w0.T, inverse=True),
+                            _bit_transpose32(w1.T, inverse=True)], axis=0).T
 
 
 def _group_widths(gtops: jax.Array, g: int) -> jax.Array:
-    """int32[n, 32]: bits group ``g`` contributes to each stream-major plane
+    """int32[32, T]: bits group ``g`` contributes to each stream-major plane
     (its size when present, else 0) — a pure function of the header."""
-    j = jnp.arange(32, dtype=jnp.int32)[None, :]
-    present = gtops[:, g][:, None] + j >= 32  # p = 31 - j < gtops[g]
+    present = gtops[g:g + 1] + _plane_rows() >= 32  # p = 31 - j < gtops[g]
     return jnp.where(present, jnp.int32(int(GROUP_SIZES[g])), 0)
 
 
 def _plane_payloads(u: jax.Array, gtops: jax.Array):
     """Assemble every plane's <= 64-bit compacted payload at once.
 
-    ``u``: uint32[n, 64] negabinary coefficients in sequency order. Returns
-    (plo, phi) uint32[n, 32]: plane j's payload bits [0, 32) and [32, 64).
-    A group's run is its coefficients' plane-j bits in rank order; a bit set
-    at plane p implies bitlength > p, i.e. the group is present — so absent
-    groups contribute zero runs with no masking.  Runs are sliced from the
-    transposed plane bit-matrix at static offsets and compacted to the
-    header-derived within-plane offsets (accumulated group widths); a run
-    spans at most two of the payload's words (run offset + run width <= 64),
-    so compaction is a masked shift/OR sum over the 10 sequency segments.
+    ``u``: uint32[64, T] CM negabinary coefficients in sequency order.
+    Returns (plo, phi) uint32[32, T]: plane j's payload bits [0, 32) and
+    [32, 64).  A group's run is its coefficients' plane-j bits in rank
+    order; a bit set at plane p implies bitlength > p, i.e. the group is
+    present — so absent groups contribute zero runs with no masking.  Runs
+    are sliced from the transposed plane bit-matrix at static offsets and
+    compacted to the header-derived within-plane offsets (accumulated group
+    widths); a run spans at most two of the payload's words (run offset +
+    run width <= 64), so compaction is a masked shift/OR sum over the 10
+    sequency segments.
     """
-    w0, w1 = _plane_words(u)
-    n = u.shape[0]
-    plo = jnp.zeros((n, 32), jnp.uint32)
-    phi = jnp.zeros((n, 32), jnp.uint32)
-    woff = jnp.zeros((n, 32), jnp.int32)
+    w0, w1 = _bit_transpose32(u[:32]), _bit_transpose32(u[32:])
+    plo = jnp.zeros(w0.shape, jnp.uint32)
+    phi = jnp.zeros(w0.shape, jnp.uint32)
+    woff = jnp.zeros(w0.shape, jnp.int32)
     for g in range(N_GROUPS):
         src = w0 if _FIXED_START[g] < 32 else w1
         s0 = jnp.uint32(_FIXED_START[g] & 31)
@@ -392,14 +534,14 @@ def _plane_payloads(u: jax.Array, gtops: jax.Array):
     return plo, phi
 
 
-def _encode_words_impl(u: jax.Array, gtops: jax.Array, rate: int) -> jax.Array:
-    """Un-jitted encode body — pure elementwise/slice jnp, so the fused
-    Pallas kernel (``repro.kernels.zfp_fused``) traces the *same* code in
-    VMEM and the streams agree across paths by construction."""
+def _encode_words_cm(u: jax.Array, gtops: jax.Array, rate: int) -> jax.Array:
+    """CM encode: (u uint32[64, T] sequency order, gtops int32[10, T]) ->
+    uint32[wpb, T] stream words.  Pure elementwise/slice/roll jnp, so the
+    fused Pallas kernel (``repro.kernels.zfp_fused``) traces the *same*
+    code in VMEM and the streams agree across paths by construction."""
     budget = rate * 64 - _HEADER_BITS
     wpb = (budget + 31) // 32
-    gtops = gtops.astype(jnp.int32)
-    OFF, keep = _plane_offsets(gtops, budget)
+    OFF, keep = _plane_offsets_cm(gtops, budget)
     plo, phi = _plane_payloads(u, gtops)
     mlo, mhi = _mask64(keep)
     plo = plo & mlo
@@ -409,21 +551,22 @@ def _encode_words_impl(u: jax.Array, gtops: jax.Array, rate: int) -> jax.Array:
     c0 = plo << sh
     c1 = ((plo >> 1) >> (jnp.uint32(31) - sh)) | (phi << sh)
     c2 = (phi >> 1) >> (jnp.uint32(31) - sh)
-    cols = []
-    for j in range(wpb):
+    rows = []
+    for k in range(wpb):
         # Bit positions are globally disjoint, so OR-ing == bit placement.
         contrib = (
-            jnp.where(w0 == j, c0, jnp.uint32(0))
-            | jnp.where(w0 + 1 == j, c1, jnp.uint32(0))
-            | jnp.where(w0 + 2 == j, c2, jnp.uint32(0))
+            jnp.where(w0 == k, c0, jnp.uint32(0))
+            | jnp.where(w0 + 1 == k, c1, jnp.uint32(0))
+            | jnp.where(w0 + 2 == k, c2, jnp.uint32(0))
         )
-        cols.append(jnp.sum(contrib, axis=1, dtype=jnp.uint32))
-    return jnp.stack(cols, axis=1)
+        rows.append(bitpack.or_sum(contrib, axis=0))
+    return jnp.concatenate(rows, axis=0)
 
 
 @partial(jax.jit, static_argnames=("rate",))
 def encode_words(u: jax.Array, gtops: jax.Array, rate: int) -> jax.Array:
-    """Word-level embedded encode: (u, gtops) -> uint32[n, wpb] stream.
+    """Word-level embedded encode: (u uint32[n, 64], gtops [n, 10]) ->
+    uint32[n, wpb] stream.
 
     Bit-identical to the reference per-plane formulation (tests pin seed
     streams).  Plane payloads land word-aligned-or-straddling, so each plane
@@ -431,14 +574,15 @@ def encode_words(u: jax.Array, gtops: jax.Array, rate: int) -> jax.Array:
     sum over the 32 planes per word — O(words-per-block) vector passes, no
     scatter.
     """
-    return _encode_words_impl(u, gtops, rate)
+    return _encode_words_cm(u.T, gtops.astype(jnp.int32).T, rate).T
 
 
 def _extract_coeffs(g0: jax.Array, g1: jax.Array, g2: jax.Array,
                     OFF: jax.Array, keep: jax.Array, gtops: jax.Array) -> jax.Array:
-    """Shared decode tail: the 3 fetched words per plane -> uint32[n, 64]
-    sequency-order coefficients.  Pure elementwise/slice jnp (reused inside
-    the fused Pallas decode kernel, which fetches the words without gathers).
+    """Shared CM decode tail: the 3 fetched words per plane (uint32[32, T]
+    each) -> uint32[64, T] sequency-order coefficients.  Pure elementwise/
+    slice/roll jnp (reused inside the fused Pallas decode kernel, which
+    fetches the words without gathers).
     """
     sh = (OFF & 31).astype(jnp.uint32)
     plo = (g0 >> sh) | ((g1 << 1) << (jnp.uint32(31) - sh))
@@ -449,10 +593,9 @@ def _extract_coeffs(g0: jax.Array, g1: jax.Array, g2: jax.Array,
     # Extract each group's run from its compacted plane payload, place it at
     # the group's static offset in the plane bit-matrix, then transpose the
     # matrix back into per-coefficient words.
-    n32 = plo.shape
-    w0m = jnp.zeros(n32, jnp.uint32)
-    w1m = jnp.zeros(n32, jnp.uint32)
-    woff = jnp.zeros(n32, jnp.int32)
+    w0m = jnp.zeros(plo.shape, jnp.uint32)
+    w1m = jnp.zeros(plo.shape, jnp.uint32)
+    woff = jnp.zeros(plo.shape, jnp.int32)
     for g in range(N_GROUPS):
         o1 = (woff & 31).astype(jnp.uint32)
         in_hi = woff >= 32
@@ -466,28 +609,30 @@ def _extract_coeffs(g0: jax.Array, g1: jax.Array, g2: jax.Array,
         else:
             w1m = w1m | (run << jnp.uint32(_FIXED_START[g] - 32))
         woff = woff + wg
-    return _coef_words(w0m, w1m)
+    return jnp.concatenate([_bit_transpose32(w0m, inverse=True),
+                            _bit_transpose32(w1m, inverse=True)], axis=0)
+
+
+def _decode_words_cm(words: jax.Array, gtops: jax.Array, rate: int) -> jax.Array:
+    """CM decode: uint32[wpb, T] stream + int32[10, T] header ->
+    uint32[64, T] sequency-order coefficients.  Each plane's <= 64-bit
+    payload spans at most 3 stream words, fetched with three gathers along
+    the word axis (a word index past the block only ever holds bits beyond
+    the budget, which ``keep`` masks, so clipping it is exact)."""
+    budget = rate * 64 - _HEADER_BITS
+    OFF, keep = _plane_offsets_cm(gtops, budget)
+    lim = words.shape[0] - 1
+    w0 = OFF >> 5
+    g0, g1, g2 = (jnp.take_along_axis(words, jnp.clip(w0 + k, 0, lim), axis=0)
+                  for k in range(3))
+    return _extract_coeffs(g0, g1, g2, OFF, keep, gtops)
 
 
 @partial(jax.jit, static_argnames=("rate",))
 def decode_words(words: jax.Array, gtops: jax.Array, rate: int) -> jax.Array:
     """Inverse of :func:`encode_words`: stream -> uint32[n, 64] sequency-order
-    negabinary coefficients (exactly the bits the budget admitted).
-
-    Each plane's <= 64-bit payload spans at most 3 stream words, fetched with
-    three flat gathers (vs one full-buffer gather per bit plane before)."""
-    budget = rate * 64 - _HEADER_BITS
-    n, wpb = words.shape
-    gtops = gtops.astype(jnp.int32)
-    OFF, keep = _plane_offsets(gtops, budget)
-    flat = words.reshape(-1)
-    row0 = jnp.arange(n, dtype=jnp.int32)[:, None] * wpb
-    lim = n * wpb - 1
-    w0 = OFF >> 5
-    g0 = flat[jnp.clip(row0 + w0, 0, lim)]
-    g1 = flat[jnp.clip(row0 + w0 + 1, 0, lim)]
-    g2 = flat[jnp.clip(row0 + w0 + 2, 0, lim)]
-    return _extract_coeffs(g0, g1, g2, OFF, keep, gtops)
+    negabinary coefficients (exactly the bits the budget admitted)."""
+    return _decode_words_cm(words.T, gtops.astype(jnp.int32).T, rate).T
 
 
 def n_blocks_for(shape) -> int:
@@ -523,33 +668,23 @@ def compress(x: jax.Array, rate: int) -> ZFPCompressed:
     """Fixed-rate compress a 3-D float32 field at ``rate`` bits/value."""
     assert x.ndim == 3, "TPU-ZFP operates on 3-D fields; reshape first (see api.py)"
     payload_words(rate)  # validates the rate
-    u, emax, gtops = block_transform(x)
-    words = encode_words(u, gtops, rate)
-    return ZFPCompressed(words, emax, gtops.astype(jnp.uint8), x.shape, rate)
-
-
-def _take_static(u: jax.Array, perm) -> jax.Array:
-    """Static column permutation as 64 unit slices + concat — the Pallas-safe
-    form (a kernel body may not capture a constant index array; static lane
-    slices lower fine)."""
-    return jnp.concatenate([u[:, int(p):int(p) + 1] for p in perm], axis=1)
-
-
-def _blocks_from_indexed(u_idx: jax.Array, emax: jax.Array) -> jax.Array:
-    """Invert stages 1-3: *index-order* coefficients + emax -> f32 blocks.
-    Pure jnp (shared with the fused Pallas decode kernel)."""
-    n = u_idx.shape[0]
-    coef = inv_negabinary(u_idx).reshape(n, 4, 4, 4)
-    ints = _inv_lift3d(coef)
-    e = emax.astype(jnp.int32) - _EMAX_BIAS
-    nonzero = emax.astype(jnp.int32) > 0
-    scale = jnp.where(nonzero, exact_exp2(e - Q), 0.0)
-    return ints.astype(jnp.float32) * scale[:, None, None, None]
+    u, emax, gtops = _transform_cm(_carve_cm(x.astype(jnp.float32)))
+    words = _encode_words_cm(u, gtops, rate)
+    return ZFPCompressed(words.T, emax[0].astype(jnp.uint8),
+                         gtops.T.astype(jnp.uint8), x.shape, rate)
 
 
 def _blocks_from_coeffs(u: jax.Array, emax: jax.Array) -> jax.Array:
-    """Invert stages 1-4: sequency-order coefficients + emax -> f32 blocks."""
-    return _blocks_from_indexed(u[:, IPERM], emax)
+    """Invert stages 1-4: sequency-order coefficients uint32[n, 64] + emax
+    -> f32 blocks (n, 4, 4, 4)."""
+    b = _inverse_cm(u.T, emax.astype(jnp.int32)[None, :])
+    return b.T.reshape(-1, 4, 4, 4)
+
+
+def _decode_cm(c: ZFPCompressed) -> jax.Array:
+    """Stream -> CM index-order f32 blocks (64, n_blocks)."""
+    u = _decode_words_cm(c.words.T, c.gtops.astype(jnp.int32).T, c.rate)
+    return _inverse_cm(u, c.emax.astype(jnp.int32)[None, :])
 
 
 def blocks_from_stream(words: jax.Array, emax: jax.Array, gtops: jax.Array,
@@ -561,8 +696,7 @@ def blocks_from_stream(words: jax.Array, emax: jax.Array, gtops: jax.Array,
 
 @jax.jit
 def decompress(c: ZFPCompressed) -> jax.Array:
-    blocks = blocks_from_stream(c.words, c.emax, c.gtops, c.rate)
-    return _uncarve_blocks(blocks, c.shape)
+    return _uncarve_cm(_decode_cm(c), c.shape)
 
 
 def compressed_nbytes(c: ZFPCompressed) -> int:

@@ -20,15 +20,8 @@ Two halves, mirroring the storage-side compressor split:
   face) so seams decode bitwise-identically to the single-device path.
   Snapshots compress where they live; the raw field never crosses the
   interconnect and never gathers to host.
-
-Importing this package installs the :mod:`repro.compat` jax polyfills, so
-callers (and tests) can use the current-jax mesh API on the 0.4.x line.
 """
 
-from repro import compat as _compat
-
-_compat.install()
-
-from repro.dist import collectives, insitu, sharding  # noqa: E402,F401
+from repro.dist import collectives, insitu, sharding  # noqa: F401
 
 __all__ = ["collectives", "insitu", "sharding"]

@@ -247,6 +247,56 @@ def _resolve_spec(field, mesh, spec):
     return spec
 
 
+def check_eligible(field, codec: str, mesh, spec=None, *,
+                   backend: str = "auto") -> tuple:
+    """Every check :func:`sharded_compress` makes before it traces, on the
+    field's shape and partition alone: raises ``NotImplementedError`` /
+    ``ValueError`` for a field it cannot compress and returns ``(layout,
+    local_shape)`` otherwise.  Callers that skip ineligible fields run this
+    first, so an error raised while *compiling* an eligible one (a kernel
+    the backend cannot lower) propagates instead of being mistaken for an
+    ineligible field."""
+    spec = _resolve_spec(field, mesh, spec)
+    sizes = dict(mesh.shape)
+    layout = partition_layout(np.shape(field), spec, mesh)
+    local = _local_shape(np.shape(field), layout, sizes)
+    if codec == "sz":
+        if backend not in ("auto", "core", "kernel"):
+            raise ValueError(f"unknown SZ backend {backend!r}; want core|kernel")
+        n = int(np.prod(local))
+        if n * 32 >= 2**31:
+            raise ValueError(f"SZ shard of {n} values overflows the packer's "
+                             "int32 bit offsets; chunk the field")
+        if backend == "kernel":
+            from repro.kernels import lorenzo3d as _lor
+
+            if len(local) != 3:
+                raise ValueError("SZ kernel backend operates on 3-D fields")
+            # every local extent must be a tile multiple — partitioned axes
+            # because per-tile prediction must not straddle the seam, and
+            # non-partitioned axes because the stream/decode contract here
+            # carries no padded shape (ops pads internally, but a padded
+            # per-shard stream would be undecodable from `local` alone)
+            for ext, ax, tile in zip(local, layout, _lor.TILE):
+                if ext % tile:
+                    raise ValueError(
+                        f"SZ kernel backend: shard extent {ext} (axis {ax!r}) "
+                        f"not a multiple of the {_lor.TILE} tile")
+    elif codec == "zfp":
+        if len(local) != 3:
+            raise ValueError("ZFP operates on 3-D fields; reshape first "
+                             "(the HACC 1-D layout is (N/64, 8, 8))")
+        for ext, ax in zip(local, layout):
+            if not zfp_core.shard_extent_aligned(ext, sizes.get(ax, 1) if ax else 1):
+                raise ValueError(
+                    f"ZFP shard extent {ext} on axis {ax!r} not a multiple of "
+                    f"{zfp_core.BLOCK_SIDE}: a seam inside a 4^3 block would "
+                    "change the stream (DESIGN.md §7)")
+    else:
+        raise ValueError(f"unknown codec {codec!r}; want sz|zfp")
+    return layout, local
+
+
 def sharded_compress(field, codec: str, mesh, spec=None, *, eb=None,
                      rate: Optional[int] = None, halo: bool = True,
                      backend: str = "auto", path: str = "auto"):
@@ -272,10 +322,9 @@ def sharded_compress(field, codec: str, mesh, spec=None, *, eb=None,
     core elsewhere); all ZFP paths emit byte-identical streams.
     """
     field = jnp.asarray(field)
+    layout, local = check_eligible(field, codec, mesh, spec, backend=backend)
     spec = _resolve_spec(field, mesh, spec)
     sizes = dict(mesh.shape)
-    layout = partition_layout(field.shape, spec, mesh)
-    local = _local_shape(field.shape, layout, sizes)
     stack = _stack_axes(layout)
     in_spec = PS(*layout)
     out_stack = PS(stack) if stack else PS()
@@ -285,24 +334,8 @@ def sharded_compress(field, codec: str, mesh, spec=None, *, eb=None,
             raise ValueError("SZ requires eb=")
         if backend == "auto":
             backend = "core"
-        if backend not in ("core", "kernel"):
-            raise ValueError(f"unknown SZ backend {backend!r}; want core|kernel")
         if backend == "kernel":
-            from repro.kernels import lorenzo3d as _lor
             from repro.kernels import ops as kops
-
-            if len(local) != 3:
-                raise ValueError("SZ kernel backend operates on 3-D fields")
-            # every local extent must be a tile multiple — partitioned axes
-            # because per-tile prediction must not straddle the seam, and
-            # non-partitioned axes because the stream/decode contract here
-            # carries no padded shape (ops pads internally, but a padded
-            # per-shard stream would be undecodable from `local` alone)
-            for ext, ax, tile in zip(local, layout, _lor.TILE):
-                if ext % tile:
-                    raise ValueError(
-                        f"SZ kernel backend: shard extent {ext} (axis {ax!r}) "
-                        f"not a multiple of the {_lor.TILE} tile")
 
         def body(x):
             x = x.astype(jnp.float32)
@@ -330,15 +363,6 @@ def sharded_compress(field, codec: str, mesh, spec=None, *, eb=None,
     if codec == "zfp":
         if rate is None:
             raise ValueError("ZFP requires rate=")
-        if len(local) != 3:
-            raise ValueError("ZFP operates on 3-D fields; reshape first "
-                             "(the HACC 1-D layout is (N/64, 8, 8))")
-        for ext, ax in zip(local, layout):
-            if not zfp_core.shard_extent_aligned(ext, sizes.get(ax, 1) if ax else 1):
-                raise ValueError(
-                    f"ZFP shard extent {ext} on axis {ax!r} not a multiple of "
-                    f"{zfp_core.BLOCK_SIDE}: a seam inside a 4^3 block would "
-                    "change the stream (DESIGN.md §7)")
         use_kernel = backend == "kernel" or (
             backend == "auto" and jax.default_backend() == "tpu")
 

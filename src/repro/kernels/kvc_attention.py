@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 SEQ_CHUNK = 128
 
 
@@ -38,13 +40,16 @@ def _kvc_kernel(len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]  # (H, D)
+    q = q_ref[0].astype(jnp.float32)  # (H, D)
     k = kc_ref[0].astype(jnp.float32) * ks_ref[0][:, :, None]  # (C, H, D)
     v = vc_ref[0].astype(jnp.float32) * vs_ref[0][:, :, None]
     scale = q.shape[-1] ** -0.5
-    logits = jnp.einsum("hd,chd->hc", q.astype(jnp.float32), k) * scale
+    # per-head q.k as a VPU multiply + lane reduction: one query row per
+    # head is no MXU shape, and the TPU lowering rejects this batched
+    # einsum's dimension numbers
+    logits = jnp.sum(k * q[None], axis=-1).T * scale  # (H, C)
     pos = s_idx * SEQ_CHUNK + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    mask = pos <= len_ref[0, 0]
+    mask = pos <= len_ref[pl.program_id(0), 0]
     logits = jnp.where(mask, logits, -1e30)
 
     m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
@@ -56,7 +61,7 @@ def _kvc_kernel(len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
     # independent of whatever the recycled cache rows hold
     p = jnp.exp(logits - m_new) * mask.astype(jnp.float32)
     l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
-    acc_new = acc_prev * alpha + jnp.einsum("hc,chd->hd", p, v)
+    acc_new = acc_prev * alpha + jnp.sum(v * p.T[:, :, None], axis=0)
     m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc_new
 
     @pl.when(s_idx == n_chunks - 1)
@@ -67,7 +72,7 @@ def _kvc_kernel(len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kvc_decode_attention(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
                          v_codes: jax.Array, v_scale: jax.Array,
-                         index: jax.Array, interpret: bool = True) -> jax.Array:
+                         index: jax.Array, interpret: bool | None = None) -> jax.Array:
     """q: (B, H, D); codes: (B, S, H, D) int8; scales: (B, S, H) f32;
     index: () shared position or (B,) per-slot positions — each lane b
     attends to cache[0..index[b]] (continuous batching admits requests at
@@ -84,7 +89,8 @@ def kvc_decode_attention(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0), memory_space=pltpu.SMEM),
+            # every lane's position, whole in SMEM (read at program_id(0))
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, h, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, SEQ_CHUNK, h, d), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, SEQ_CHUNK, h), lambda i, j: (i, j, 0)),
@@ -97,5 +103,5 @@ def kvc_decode_attention(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(idx, q, k_codes, k_scale, v_codes, v_scale)

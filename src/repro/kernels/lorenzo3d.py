@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import default_interpret
+
 TILE = (8, 64, 128)
 
 
@@ -55,21 +57,40 @@ def guarded_eb(x: jax.Array, eb) -> jax.Array:
     return sz.internal_bound(jnp.max(jnp.abs(x)), eb)
 
 
-def _lorenzo_kernel(eb_ref, x_ref, delta_ref):
-    x = x_ref[...]
-    inv2eb = 1.0 / (2.0 * eb_ref[0, 0])
-    q = jnp.round(x * inv2eb).astype(jnp.int32)
+def lorenzo_residual(q: jax.Array) -> jax.Array:
+    """3-D Lorenzo residual of an int32 tile (prediction resets at the tile
+    border): one backward difference per axis, as roll + iota select."""
     d = q
     for axis in range(3):
         rolled = jnp.roll(d, 1, axis=axis)
         idx = jax.lax.broadcasted_iota(jnp.int32, d.shape, axis)
-        prev = jnp.where(idx == 0, 0, rolled)
-        d = d - prev
-    delta_ref[...] = d
+        d = d - jnp.where(idx == 0, 0, rolled)
+    return d
+
+
+def prefix_sum(d: jax.Array, axis: int) -> jax.Array:
+    """Inclusive int32 prefix sum along ``axis`` of a tile, in
+    ceil(log2(n)) shifted adds (roll + iota mask) — the Pallas TPU lowering
+    has no cumsum.  Bit-exact against ``jnp.cumsum``: int32 adds wrap modulo
+    2^32 whatever order they run in."""
+    n = d.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, d.shape, axis)
+    s = 1
+    while s < n:
+        d = d + jnp.where(idx >= s, jnp.roll(d, s, axis=axis), 0)
+        s *= 2
+    return d
+
+
+def _lorenzo_kernel(eb_ref, x_ref, delta_ref):
+    inv2eb = 1.0 / (2.0 * eb_ref[0, 0])
+    q = jnp.round(x_ref[...] * inv2eb).astype(jnp.int32)
+    delta_ref[...] = lorenzo_residual(q)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lorenzo3d_quantize(x: jax.Array, eb_i: jax.Array, interpret: bool = True) -> jax.Array:
+def lorenzo3d_quantize(x: jax.Array, eb_i: jax.Array,
+                       interpret: bool | None = None) -> jax.Array:
     """f32 (Z, Y, X) -> int32 Lorenzo residuals, tile-blocked. ``eb_i`` is
     the *guarded* bound (see guarded_eb). Shape must be TILE-padded."""
     z, y, w = x.shape
@@ -86,20 +107,21 @@ def lorenzo3d_quantize(x: jax.Array, eb_i: jax.Array, interpret: bool = True) ->
             pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
         ],
         out_specs=pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(eb_arr, x)
 
 
 def _reconstruct_kernel(eb_ref, delta_ref, out_ref):
     d = delta_ref[...]
     for axis in range(3):
-        d = jnp.cumsum(d, axis=axis)
+        d = prefix_sum(d, axis)
     out_ref[...] = d.astype(jnp.float32) * (2.0 * eb_ref[0, 0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lorenzo3d_reconstruct(delta: jax.Array, eb_i: jax.Array, interpret: bool = True) -> jax.Array:
-    """Inverse: per-tile 3-fold cumsum + dequantization (decompression)."""
+def lorenzo3d_reconstruct(delta: jax.Array, eb_i: jax.Array,
+                          interpret: bool | None = None) -> jax.Array:
+    """Inverse: per-tile 3-fold prefix sum + dequantization (decompression)."""
     z, y, w = delta.shape
     tz, ty, tw = TILE
     assert z % tz == 0 and y % ty == 0 and w % tw == 0
@@ -114,5 +136,5 @@ def lorenzo3d_reconstruct(delta: jax.Array, eb_i: jax.Array, interpret: bool = T
             pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
         ],
         out_specs=pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(eb_arr, delta)

@@ -1,7 +1,7 @@
 """Jitted public wrappers around the Pallas kernels: padding/carving to tile
-multiples, platform dispatch (interpret=True on CPU — the kernels TARGET
-TPU; this container validates them in interpret mode), and integration with
-the repro.core bitstream layer.
+multiples, path dispatch, and integration with the repro.core bitstream
+layer.  Every kernel compiles on TPU and runs in Pallas interpret mode
+elsewhere (``repro.kernels.default_interpret``).
 """
 
 from __future__ import annotations
@@ -14,7 +14,6 @@ import numpy as np
 
 from repro.core import bitpack
 from repro.core import zfp as zfp_core
-from repro.kernels import default_interpret as _interpret
 from repro.kernels import kvc_attention as _kvc
 from repro.kernels import lorenzo3d as _lor
 from repro.kernels import sz_fused as _szf
@@ -53,37 +52,41 @@ def sz_compress_kernel(x: jax.Array, eb: float, path: str = "auto", eb_i=None):
     if eb_i is None:
         eb_i = _lor.guarded_eb(xp, eb)
     if _resolve_sz_path(path) == "fused":
-        packed = _szf.fused_compress(xp, eb_i, interpret=_interpret())
+        packed = _szf.fused_compress(xp, eb_i)
     else:
-        delta = _lor.lorenzo3d_quantize(xp, eb_i, interpret=_interpret())
+        delta = _lor.lorenzo3d_quantize(xp, eb_i)
         packed = bitpack.pack_codes(_szf.tile_major_flatten(delta))
     return packed, xp.shape, eb_i
 
 
 def sz_decompress_kernel(packed, padded_shape, orig_shape, eb_i, path: str = "auto") -> jax.Array:
     if _resolve_sz_path(path) == "fused":
-        xr = _szf.fused_decompress(packed, tuple(padded_shape), eb_i, interpret=_interpret())
+        xr = _szf.fused_decompress(packed, tuple(padded_shape), eb_i)
     else:
         flat = bitpack.unpack_codes(packed)
         delta = _szf.tile_major_unflatten(flat, tuple(padded_shape))
-        xr = _lor.lorenzo3d_reconstruct(delta, eb_i, interpret=_interpret())
+        xr = _lor.lorenzo3d_reconstruct(delta, eb_i)
     return xr[tuple(slice(0, s) for s in orig_shape)]
 
 
 # ------------------------------------------------------------ TPU-ZFP -----
 
 
+def _pad_lanes(a: jax.Array, tile: int) -> jax.Array:
+    """Pad a coefficient-major (rows, NB) operand to a ``tile`` multiple of
+    blocks (zero blocks: emax 0, no payload)."""
+    pad = (-a.shape[1]) % tile
+    return jnp.pad(a, ((0, 0), (0, pad))) if pad else a
+
+
 def zfp_transform_kernel(x: jax.Array):
     """Kernel-path ZFP stages 1-3 on a 3-D field: returns (u in sequency
     order, emax u8, gtops i32) matching repro.core.zfp.block_transform."""
-    blocks = zfp_core._carve_blocks(x.astype(jnp.float32))
-    nb = blocks.shape[0]
-    pad = (-nb) % _zfp.BLOCKS_PER_TILE
-    if pad:
-        blocks = jnp.pad(blocks, ((0, pad), (0, 0), (0, 0), (0, 0)))
-    u, emax, gtops = _zfp.zfp3d_transform(blocks, interpret=_interpret())
-    u = u[:nb][:, zfp_core.PERM]  # sequency order (permutation stays jnp)
-    return u, emax[:nb].astype(jnp.uint8), gtops[:nb]
+    cm = zfp_core._carve_cm(x.astype(jnp.float32))
+    nb = cm.shape[1]
+    u, emax, gtops = _zfp.zfp3d_transform_cm(_pad_lanes(cm, _zfp.BLOCKS_PER_TILE))
+    u = u[zfp_core.PERM, :nb].T  # sequency order (permutation stays jnp)
+    return u, emax[0, :nb].astype(jnp.uint8), gtops[:, :nb].T
 
 
 def _resolve_zfp_path(path: str) -> str:
@@ -98,30 +101,23 @@ def _resolve_zfp_path(path: str) -> str:
     return path
 
 
-def _pad_blocks(a: jax.Array, tile: int) -> jax.Array:
-    pad = (-a.shape[0]) % tile
-    if pad:
-        a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-    return a
-
-
 def zfp_compress_kernel(x: jax.Array, rate: int, path: str = "auto") -> zfp_core.ZFPCompressed:
     """Kernel-path fixed-rate ZFP compress of a 3-D field.  Returns the same
     ``ZFPCompressed`` pytree as ``repro.core.zfp.compress`` — byte-identical
     ``words``/``emax``/``gtops`` on every path."""
-    blocks = zfp_core._carve_blocks(x.astype(jnp.float32))
-    nb = blocks.shape[0]
+    zfp_core.payload_words(rate)  # validates the rate
+    cm = zfp_core._carve_cm(x.astype(jnp.float32))
+    nb = cm.shape[1]
     if _resolve_zfp_path(path) == "fused":
-        blocks = _pad_blocks(blocks, _zfpf.BLOCKS_PER_TILE)
-        words, emax, gtops = _zfpf.fused_compress_blocks(
-            blocks, rate, interpret=_interpret())
+        words, emax, gtops = _zfpf.fused_compress_cm(
+            _pad_lanes(cm, _zfpf.BLOCKS_PER_TILE), rate)
     else:
-        blocks = _pad_blocks(blocks, _zfp.BLOCKS_PER_TILE)
-        u, emax, gtops = _zfp.zfp3d_transform(blocks, interpret=_interpret())
-        words = zfp_core.encode_words(u[:, zfp_core.PERM], gtops, rate)
+        u, emax, gtops = _zfp.zfp3d_transform_cm(
+            _pad_lanes(cm, _zfp.BLOCKS_PER_TILE))
+        words = zfp_core._encode_words_cm(u[zfp_core.PERM], gtops, rate)
     return zfp_core.ZFPCompressed(
-        words[:nb], emax[:nb].astype(jnp.uint8), gtops[:nb].astype(jnp.uint8),
-        x.shape, rate)
+        words[:, :nb].T, emax[0, :nb].astype(jnp.uint8),
+        gtops[:, :nb].T.astype(jnp.uint8), x.shape, rate)
 
 
 def zfp_decompress_kernel(c: zfp_core.ZFPCompressed, path: str = "auto") -> jax.Array:
@@ -129,12 +125,11 @@ def zfp_decompress_kernel(c: zfp_core.ZFPCompressed, path: str = "auto") -> jax.
     ``repro.core.zfp.compress`` streams — same layout)."""
     if _resolve_zfp_path(path) == "fused":
         nb = c.words.shape[0]
-        words = _pad_blocks(c.words, _zfpf.BLOCKS_PER_TILE)
-        emax = _pad_blocks(c.emax.astype(jnp.int32), _zfpf.BLOCKS_PER_TILE)
-        gtops = _pad_blocks(c.gtops.astype(jnp.int32), _zfpf.BLOCKS_PER_TILE)
-        blocks = _zfpf.fused_decompress_blocks(
-            words, emax, gtops, c.rate, interpret=_interpret())
-        return zfp_core._uncarve_blocks(blocks[:nb], c.shape)
+        t = _zfpf.BLOCKS_PER_TILE
+        blocks = _zfpf.fused_decompress_cm(
+            _pad_lanes(c.words.T, t), _pad_lanes(c.emax.astype(jnp.int32)[None, :], t),
+            _pad_lanes(c.gtops.astype(jnp.int32).T, t), c.rate)
+        return zfp_core._uncarve_cm(blocks[:, :nb], c.shape)
     return zfp_core.decompress(c)
 
 
@@ -156,4 +151,4 @@ def kvc_attention(q: jax.Array, k_codes, k_scale, v_codes, v_scale, index):
         k_scale = jnp.pad(k_scale, zs)
         v_scale = jnp.pad(v_scale, zs)
     return _kvc.kvc_decode_attention(q, k_codes, k_scale, v_codes, v_scale,
-                                     jnp.asarray(index), interpret=_interpret())
+                                     jnp.asarray(index))

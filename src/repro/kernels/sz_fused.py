@@ -45,8 +45,9 @@ offset ``i*w`` spans at most two of the block's 64 payload words, so the
 payload is a one-hot-masked sum over codes (a dense VPU reduction, no
 VMEM scatter).  Decode inverts it with the transposed one-hot (gather-free).
 
-The kernels TARGET TPU; this container validates them in interpret mode
-(no TPU), which is how the byte-identity tests run.
+The kernels compile for TPU (``tests/test_tpu_compile.py`` compiles each for
+a v5e); elsewhere they run in Pallas interpret mode, which is how the
+byte-identity tests run.
 """
 
 from __future__ import annotations
@@ -60,6 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bitpack
+from repro.kernels import default_interpret
 from repro.kernels import lorenzo3d as _lor
 
 TILE = _lor.TILE  # (8, 64, 128)
@@ -94,72 +96,128 @@ def tile_major_unflatten(flat: jax.Array, padded_shape: tuple[int, ...]) -> jax.
 
 
 # ------------------------------------------------------------- encode -----
+#
+# In-kernel layout: a tile's 65536 codes as a (512, 128) lane-dense array —
+# row r holds the C-order codes r*128 .. r*128+127, i.e. block 2r in lanes
+# 0..63 and block 2r+1 in lanes 64..127 (BLOCK = 64 = half a lane row).  The
+# per-block widths are (R, 1) columns ``wa`` / ``wb`` for the two halves, and
+# a block's payload words land in the same half of the same row, so the
+# (512, 128) word output *is* the (1024, 64) per-block payload matrix.  No
+# in-kernel reshape ever splits the lane dimension (the TPU lowering has no
+# such shape cast).
+
+ROWS = CODES_PER_TILE // 128  # 512 rows of two 64-code blocks
 
 
-def _in_block_layout(width: jax.Array):
-    """Per-code (lo-word index, bit offset) inside a block payload.
+def _half_layout(shape):
+    """(lane iota, upper-half mask, in-block code index) for a row-pair array."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    upper = lane >= bitpack.BLOCK
+    return lane, upper, lane & (bitpack.BLOCK - 1)
 
-    ``width``: int32[nb] block widths.  Returns int32[nb, BLOCK] wlo and
-    uint32[nb, BLOCK] off with ``i * w = 32 * wlo + off``.
+
+def _row_layout(wa: jax.Array, wb: jax.Array, shape):
+    """Per-code (width, lo-word index, bit offset) of the row-pair layout:
+    ``i * w = 32 * wlo + off`` for in-block code ``i`` of width ``w``."""
+    lane, upper, i = _half_layout(shape)
+    w = jnp.where(upper, wb, wa)
+    bitpos = i * w
+    return lane, upper, w, bitpos >> 5, (bitpos & 31).astype(jnp.uint32)
+
+
+def _pack_rows(u: jax.Array, wa: jax.Array, wb: jax.Array) -> jax.Array:
+    """Pack uint32[R, 128] row-pair codes into uint32[R, 128] payload words
+    (block 2r's words in lanes 0..63 of row r, block 2r+1's in 64..127;
+    dense from word 0, words >= 2*width are zero).
+
+    Scatter-free: each code contributes to at most two words of its block
+    (see ``bitpack.pack_codes``), realised per payload word ``j`` as a
+    one-hot-masked lane reduction over each half.  The word loop is a
+    ``fori_loop`` so the live intermediates stay at a few [R, 128] arrays
+    (unrolled, the 64 iterations' temporaries overflow scoped VMEM).
     """
-    i = jax.lax.broadcasted_iota(jnp.int32, (width.shape[0], bitpack.BLOCK), 1)
-    bitpos = i * width[:, None]
-    return bitpos >> 5, (bitpos & 31).astype(jnp.uint32)
+    lane, upper, _, wlo, off = _row_layout(wa, wb, u.shape)
+    lo = u << off
+    hi = (u >> 1) >> (jnp.uint32(31) - off)  # u >> (32 - off), 0 at off == 0
+    zero = jnp.uint32(0)
+
+    def word(j, out):
+        # Bit positions never collide, so OR-ing == bit placement.
+        contrib = jnp.where(wlo == j, lo, zero) | jnp.where(wlo + 1 == j, hi, zero)
+        col_a = bitpack.or_sum(jnp.where(upper, zero, contrib), axis=1)
+        col_b = bitpack.or_sum(jnp.where(upper, contrib, zero), axis=1)
+        return out | jnp.where(lane == j, col_a, zero) | jnp.where(
+            lane == bitpack.BLOCK + j, col_b, zero)
+
+    return jax.lax.fori_loop(0, WORDS_PER_BLOCK, word, jnp.zeros(u.shape, jnp.uint32))
+
+
+def _unpack_rows(words: jax.Array, wa: jax.Array, wb: jax.Array) -> jax.Array:
+    """Inverse of :func:`_pack_rows` (gather-free: each payload word is
+    broadcast across its half and one-hot-selected per code)."""
+    lane, upper, w, wlo, off = _row_layout(wa, wb, words.shape)
+    zero = jnp.uint32(0)
+
+    def word(j, acc):
+        # word j of each half, broadcast across its half: a one-hot lane
+        # reduction (a lane slice at a traced offset does not lower)
+        sel = bitpack.or_sum(jnp.where(lane == j, words, zero), axis=1)
+        sel_b = bitpack.or_sum(jnp.where(lane == bitpack.BLOCK + j, words, zero), axis=1)
+        wj = jnp.where(upper, sel_b, sel)
+        w_lo, w_hi = acc
+        return (w_lo | jnp.where(wlo == j, wj, zero),
+                w_hi | jnp.where(wlo + 1 == j, wj, zero))
+
+    init = (jnp.zeros(words.shape, jnp.uint32), jnp.zeros(words.shape, jnp.uint32))
+    w_lo, w_hi = jax.lax.fori_loop(0, WORDS_PER_BLOCK, word, init)
+    u = (w_lo >> off) | ((w_hi << 1) << (jnp.uint32(31) - off))
+    return u & bitpack.code_mask(w)
+
+
+def _pair_rows(a: jax.Array) -> jax.Array:
+    """[nb, BLOCK] per-block rows -> [ceil(nb/2), 128] row-pair layout."""
+    nb = a.shape[0]
+    return jnp.pad(a, ((0, nb % 2), (0, 0))).reshape(-1, 2 * bitpack.BLOCK)
+
+
+def _pair_widths(width: jax.Array):
+    w = jnp.pad(width, (0, width.shape[0] % 2)).reshape(-1, 2)
+    return w[:, 0:1], w[:, 1:2]
 
 
 def _pack_blocks(u: jax.Array, width: jax.Array) -> jax.Array:
     """Pack uint32[nb, BLOCK] codes into uint32[nb, WORDS_PER_BLOCK] payload
-    words (dense from word 0; words >= 2*width are zero).
-
-    Scatter-free: each code contributes to at most two words (see
-    ``bitpack.pack_codes``), realised as a one-hot-masked sum over the
-    block's codes.  The word loop is unrolled (static WORDS_PER_BLOCK
-    iterations) so the live intermediates stay at [nb, BLOCK] — a full
-    [nb, BLOCK, WORDS_PER_BLOCK] one-hot tensor would be ~16 MB/tile and
-    oversubscribe VMEM on real TPUs.
-    """
-    wlo, off = _in_block_layout(width)
-    lo = u << off
-    hi = (u >> 1) >> (jnp.uint32(31) - off)  # u >> (32 - off), 0 at off == 0
-    cols = []
-    for j in range(WORDS_PER_BLOCK):
-        # Bit positions never collide, so summing == OR-ing.
-        contrib = jnp.where(wlo == j, lo, jnp.uint32(0)) + jnp.where(wlo + 1 == j, hi, jnp.uint32(0))
-        cols.append(jnp.sum(contrib, axis=1))
-    return jnp.stack(cols, axis=1)
+    words: the kernel's row-pair packer (:func:`_pack_rows`) on the same
+    blocks, two per row."""
+    wa, wb = _pair_widths(width)
+    out = _pack_rows(_pair_rows(u), wa, wb)
+    return out.reshape(-1, WORDS_PER_BLOCK)[: u.shape[0]]
 
 
 def _unpack_blocks(words: jax.Array, width: jax.Array) -> jax.Array:
-    """Inverse of :func:`_pack_blocks`: uint32[nb, WORDS_PER_BLOCK] payload
-    words -> uint32[nb, BLOCK] codes (gather-free, transposed one-hot;
-    same unrolled-word-loop memory shape as :func:`_pack_blocks`)."""
-    wlo, off = _in_block_layout(width)
-    w_lo = jnp.zeros(wlo.shape, jnp.uint32)
-    w_hi = jnp.zeros(wlo.shape, jnp.uint32)
-    for j in range(WORDS_PER_BLOCK):
-        wj = words[:, j][:, None]
-        w_lo = w_lo | jnp.where(wlo == j, wj, jnp.uint32(0))
-        w_hi = w_hi | jnp.where(wlo + 1 == j, wj, jnp.uint32(0))
-    u = (w_lo >> off) | ((w_hi << 1) << (jnp.uint32(31) - off))
-    return u & bitpack.code_mask(width[:, None])
+    """Inverse of :func:`_pack_blocks` (via :func:`_unpack_rows`)."""
+    wa, wb = _pair_widths(width)
+    out = _unpack_rows(_pair_rows(words), wa, wb)
+    return out.reshape(-1, bitpack.BLOCK)[: words.shape[0]]
 
 
 def _encode_tile(eb, x, words_ref, widths_ref):
     """Shared tile body: quantize + 3-D Lorenzo + zigzag + width + pack one
-    (8, 64, 128) f32 tile into its block payload/width output refs."""
+    (8, 64, 128) f32 tile into its payload (512, 128) and width (2, 512)
+    output blocks."""
     inv2eb = 1.0 / (2.0 * eb)
     q = jnp.round(x * inv2eb).astype(jnp.int32)
-    d = q
-    for axis in range(3):
-        rolled = jnp.roll(d, 1, axis=axis)
-        idx = jax.lax.broadcasted_iota(jnp.int32, d.shape, axis)
-        prev = jnp.where(idx == 0, 0, rolled)
-        d = d - prev
-    u = bitpack.zigzag(d).reshape(BLOCKS_PER_TILE, bitpack.BLOCK)
-    width = jnp.max(bitpack.bitlength(u), axis=1)
-    words = _pack_blocks(u, width)
-    words_ref[...] = words.reshape(words_ref.shape)
-    widths_ref[...] = width.reshape(widths_ref.shape)
+    u = bitpack.zigzag(_lor.lorenzo_residual(q)).reshape(ROWS, 128)
+    _, upper, _ = _half_layout(u.shape)
+    bl = bitpack.bitlength(u)
+    wa = jnp.max(jnp.where(upper, 0, bl), axis=1, keepdims=True)
+    wb = jnp.max(jnp.where(upper, bl, 0), axis=1, keepdims=True)
+    words_ref[...] = _pack_rows(u, wa, wb).reshape(words_ref.shape)
+    # widths leave as (2, 512) rows (row h = half h of every row pair): the
+    # lane-dense transpose of the (512, 2) column pair
+    lane = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
+    cols = jnp.where(lane == 0, wa, jnp.where(lane == 1, wb, 0))
+    widths_ref[...] = cols.T[0:2].reshape(widths_ref.shape)
 
 
 def _fused_encode_kernel(eb_ref, x_ref, words_ref, widths_ref):
@@ -168,13 +226,31 @@ def _fused_encode_kernel(eb_ref, x_ref, words_ref, widths_ref):
 
 def _fused_encode_kernel_batched(eb_ref, x_ref, words_ref, widths_ref):
     # batched grid: leading dim-1 block axis carries the batch row; the
-    # per-row error bound arrives via the SMEM block indexed by the same
-    # grid axis, so one compiled kernel serves every row of the megabatch
-    _encode_tile(eb_ref[0, 0], x_ref[0], words_ref, widths_ref)
+    # per-row error bounds sit whole in SMEM, indexed by the same grid axis,
+    # so one compiled kernel serves every row of the megabatch
+    _encode_tile(eb_ref[pl.program_id(0), 0], x_ref[0], words_ref, widths_ref)
+
+
+def _widths_from_rows(rows: jax.Array) -> jax.Array:
+    """(n_tiles, 2, ROWS) kernel width rows -> int32[n_blocks] block order
+    (block 2r + h of a tile is row h, lane r).  A flat gather: the
+    equivalent transpose to a minor dimension of 2 takes the TPU compiler
+    about 90 s at 256^3."""
+    k = jnp.arange(rows.size, dtype=jnp.int32)
+    tile, b = k // BLOCKS_PER_TILE, k % BLOCKS_PER_TILE
+    return rows.reshape(-1)[tile * BLOCKS_PER_TILE + (b % 2) * ROWS + b // 2]
+
+
+def _widths_to_rows(width: jax.Array) -> jax.Array:
+    """Inverse of :func:`_widths_from_rows` (the same flat gather)."""
+    k = jnp.arange(width.size, dtype=jnp.int32)
+    tile, rest = k // BLOCKS_PER_TILE, k % BLOCKS_PER_TILE
+    src = tile * BLOCKS_PER_TILE + 2 * (rest % ROWS) + rest // ROWS
+    return width[src].reshape(-1, 2, ROWS)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _fused_encode(x: jax.Array, eb_i: jax.Array, interpret: bool = True):
+def _fused_encode(x: jax.Array, eb_i: jax.Array, interpret: bool | None = None):
     """One fused pass: f32 (Z, Y, X) -> per-block payload words + widths.
 
     Returns (uint32[n_blocks, WORDS_PER_BLOCK], int32[n_blocks]) in
@@ -183,13 +259,12 @@ def _fused_encode(x: jax.Array, eb_i: jax.Array, interpret: bool = True):
     gz, gy, gx = _grid(x.shape)
     n_tiles = gz * gy * gx
     eb_arr = jnp.asarray(eb_i, jnp.float32).reshape(1, 1)
-    # Lane-aligned output carriers: (1024, 64) words -> (512, 128),
-    # (1024,) widths -> (8, 128) per tile (pure reshapes of the same data).
+    tidx = lambda i, j, k, gy=gy, gx=gx: i * gy * gx + j * gx + k
     words, widths = pl.pallas_call(
         _fused_encode_kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((n_tiles * 512, 128), jnp.uint32),
-            jax.ShapeDtypeStruct((n_tiles * 8, 128), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles * ROWS, 128), jnp.uint32),
+            jax.ShapeDtypeStruct((n_tiles, 2, ROWS), jnp.int32),
         ),
         grid=(gz, gy, gx),
         in_specs=[
@@ -197,12 +272,12 @@ def _fused_encode(x: jax.Array, eb_i: jax.Array, interpret: bool = True):
             pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
         ],
         out_specs=(
-            pl.BlockSpec((512, 128), lambda i, j, k, gy=gy, gx=gx: (i * gy * gx + j * gx + k, 0)),
-            pl.BlockSpec((8, 128), lambda i, j, k, gy=gy, gx=gx: (i * gy * gx + j * gx + k, 0)),
+            pl.BlockSpec((ROWS, 128), lambda i, j, k: (tidx(i, j, k), 0)),
+            pl.BlockSpec((1, 2, ROWS), lambda i, j, k: (tidx(i, j, k), 0, 0)),
         ),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(eb_arr, x)
-    return (words.reshape(-1, WORDS_PER_BLOCK), widths.reshape(-1))
+    return (words.reshape(-1, WORDS_PER_BLOCK), _widths_from_rows(widths))
 
 
 def _assemble_stream(block_words: jax.Array, width: jax.Array, n: int) -> bitpack.PackedCodes:
@@ -220,7 +295,8 @@ def _assemble_stream(block_words: jax.Array, width: jax.Array, n: int) -> bitpac
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_compress(x: jax.Array, eb_i: jax.Array, interpret: bool = True) -> bitpack.PackedCodes:
+def fused_compress(x: jax.Array, eb_i: jax.Array,
+                   interpret: bool | None = None) -> bitpack.PackedCodes:
     """Fused-kernel SZ encode of a TILE-padded f32 field.  The returned
     stream is byte-identical to the XLA fallback
     (``pack_codes(tile_major_flatten(lorenzo3d_quantize(x)))``)."""
@@ -235,7 +311,7 @@ def fused_compress(x: jax.Array, eb_i: jax.Array, interpret: bool = True) -> bit
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _fused_encode_batched(x: jax.Array, eb_i: jax.Array, interpret: bool = True):
+def _fused_encode_batched(x: jax.Array, eb_i: jax.Array, interpret: bool | None = None):
     """Batched fused encode: (B, Z, Y, X) TILE-padded rows + per-row bounds
     -> per-block payload words/widths for **all** rows in one launch (grid
     gains a leading batch axis; rows never sync with the host)."""
@@ -247,25 +323,25 @@ def _fused_encode_batched(x: jax.Array, eb_i: jax.Array, interpret: bool = True)
     words, widths = pl.pallas_call(
         _fused_encode_kernel_batched,
         out_shape=(
-            jax.ShapeDtypeStruct((bsz * n_tiles * 512, 128), jnp.uint32),
-            jax.ShapeDtypeStruct((bsz * n_tiles * 8, 128), jnp.int32),
+            jax.ShapeDtypeStruct((bsz * n_tiles * ROWS, 128), jnp.uint32),
+            jax.ShapeDtypeStruct((bsz * n_tiles, 2, ROWS), jnp.int32),
         ),
         grid=(bsz, gz, gy, gx),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i, j, k: (b, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1,) + TILE, lambda b, i, j, k: (b, i, j, k)),
         ],
         out_specs=(
-            pl.BlockSpec((512, 128), lambda b, i, j, k: (tidx(b, i, j, k), 0)),
-            pl.BlockSpec((8, 128), lambda b, i, j, k: (tidx(b, i, j, k), 0)),
+            pl.BlockSpec((ROWS, 128), lambda b, i, j, k: (tidx(b, i, j, k), 0)),
+            pl.BlockSpec((1, 2, ROWS), lambda b, i, j, k: (tidx(b, i, j, k), 0, 0)),
         ),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(eb_arr, x)
-    return (words.reshape(-1, WORDS_PER_BLOCK), widths.reshape(-1))
+    return (words.reshape(-1, WORDS_PER_BLOCK), _widths_from_rows(widths))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_compress_batched(x: jax.Array, eb_i: jax.Array, interpret: bool = True):
+def fused_compress_batched(x: jax.Array, eb_i: jax.Array, interpret: bool | None = None):
     """Arena-batched fused SZ encode: (B, Z, Y, X) rows -> one contiguous
     uint32 word arena holding every row's stream back-to-back.
 
@@ -297,25 +373,25 @@ def fused_compress_batched(x: jax.Array, eb_i: jax.Array, interpret: bool = True
 # ------------------------------------------------------------- decode -----
 
 
-def _decode_tile(eb, words, width):
-    """Shared tile body: unpack + unzigzag + 3-fold cumsum + dequantize one
-    tile's payload back to its (8, 64, 128) f32 block."""
-    u = _unpack_blocks(words.reshape(BLOCKS_PER_TILE, WORDS_PER_BLOCK),
-                       width.reshape(BLOCKS_PER_TILE))
-    delta = bitpack.unzigzag(u).reshape(TILE)
-    q = delta
+def _decode_tile(eb, words, width_rows):
+    """Shared tile body: unpack + unzigzag + 3-fold prefix sum + dequantize
+    one tile's (512, 128) payload and (2, 512) widths back to its
+    (8, 64, 128) f32 block."""
+    cols = width_rows.T  # (512, 2): the two halves' widths per row pair
+    u = _unpack_rows(words, cols[:, 0:1], cols[:, 1:2])
+    q = bitpack.unzigzag(u).reshape(TILE)
     for axis in range(3):
-        q = jnp.cumsum(q, axis=axis)
+        q = _lor.prefix_sum(q, axis)
     return q.astype(jnp.float32) * (2.0 * eb)
 
 
 def _fused_decode_kernel(eb_ref, words_ref, widths_ref, out_ref):
-    out_ref[...] = _decode_tile(eb_ref[0, 0], words_ref[...], widths_ref[...])
+    out_ref[...] = _decode_tile(eb_ref[0, 0], words_ref[...], widths_ref[0])
 
 
 def _fused_decode_kernel_batched(eb_ref, words_ref, widths_ref, out_ref):
-    out_ref[...] = _decode_tile(eb_ref[0, 0], words_ref[...],
-                                widths_ref[...]).reshape(out_ref.shape)
+    out_ref[...] = _decode_tile(eb_ref[pl.program_id(0), 0], words_ref[...],
+                                widths_ref[0]).reshape(out_ref.shape)
 
 
 def _disassemble_stream(packed: bitpack.PackedCodes) -> tuple[jax.Array, jax.Array]:
@@ -334,14 +410,14 @@ def _disassemble_stream(packed: bitpack.PackedCodes) -> tuple[jax.Array, jax.Arr
 
 @functools.partial(jax.jit, static_argnames=("padded_shape", "interpret"))
 def fused_decompress(packed: bitpack.PackedCodes, padded_shape: tuple[int, ...],
-                     eb_i: jax.Array, interpret: bool = True) -> jax.Array:
-    """Fused-kernel SZ decode: unpack + unzigzag + 3-fold cumsum + dequant
-    in one VMEM tile pass (int32 codes never reach HBM)."""
+                     eb_i: jax.Array, interpret: bool | None = None) -> jax.Array:
+    """Fused-kernel SZ decode: unpack + unzigzag + 3-fold prefix sum +
+    dequant in one VMEM tile pass (int32 codes never reach HBM)."""
     gz, gy, gx = _grid(padded_shape)
     n_tiles = gz * gy * gx
     block_words, width = _disassemble_stream(packed)
-    words_c = block_words.reshape(n_tiles * 512, 128)
-    widths_c = width.reshape(n_tiles * 8, 128)
+    words_c = block_words.reshape(n_tiles * ROWS, 128)
+    widths_c = _widths_to_rows(width)
     eb_arr = jnp.asarray(eb_i, jnp.float32).reshape(1, 1)
     return pl.pallas_call(
         _fused_decode_kernel,
@@ -349,18 +425,18 @@ def fused_decompress(packed: bitpack.PackedCodes, padded_shape: tuple[int, ...],
         grid=(gz, gy, gx),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((512, 128), lambda i, j, k, gy=gy, gx=gx: (i * gy * gx + j * gx + k, 0)),
-            pl.BlockSpec((8, 128), lambda i, j, k, gy=gy, gx=gx: (i * gy * gx + j * gx + k, 0)),
+            pl.BlockSpec((ROWS, 128), lambda i, j, k, gy=gy, gx=gx: (i * gy * gx + j * gx + k, 0)),
+            pl.BlockSpec((1, 2, ROWS), lambda i, j, k, gy=gy, gx=gx: (i * gy * gx + j * gx + k, 0, 0)),
         ],
         out_specs=pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(eb_arr, words_c, widths_c)
 
 
 @functools.partial(jax.jit, static_argnames=("padded_shape", "interpret"))
 def fused_decompress_batched(arena: jax.Array, widths: jax.Array,
                              padded_shape: tuple[int, ...], eb_i: jax.Array,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool | None = None) -> jax.Array:
     """Inverse of :func:`fused_compress_batched`: the contiguous word arena
     + per-row block widths -> (B, Z, Y, X) f32 rows in one batched launch.
 
@@ -380,8 +456,8 @@ def fused_decompress_batched(arena: jax.Array, widths: jax.Array,
     vals = arena[jnp.clip(idx, 0, cap - 1)]
     block_words = jnp.where(j[None, :] < wcount[:, None], vals, jnp.uint32(0))
 
-    words_c = block_words.reshape(bsz * n_tiles * 512, 128)
-    widths_c = width.reshape(bsz * n_tiles * 8, 128)
+    words_c = block_words.reshape(bsz * n_tiles * ROWS, 128)
+    widths_c = _widths_to_rows(width)
     eb_arr = jnp.asarray(eb_i, jnp.float32).reshape(bsz, 1)
     tidx = lambda b, i, j, k, gz=gz, gy=gy, gx=gx: ((b * gz + i) * gy + j) * gx + k
     return pl.pallas_call(
@@ -389,10 +465,10 @@ def fused_decompress_batched(arena: jax.Array, widths: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bsz,) + tuple(padded_shape), jnp.float32),
         grid=(bsz, gz, gy, gx),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i, j, k: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((512, 128), lambda b, i, j, k: (tidx(b, i, j, k), 0)),
-            pl.BlockSpec((8, 128), lambda b, i, j, k: (tidx(b, i, j, k), 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((ROWS, 128), lambda b, i, j, k: (tidx(b, i, j, k), 0)),
+            pl.BlockSpec((1, 2, ROWS), lambda b, i, j, k: (tidx(b, i, j, k), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1,) + TILE, lambda b, i, j, k: (b, i, j, k)),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(eb_arr, words_c, widths_c)

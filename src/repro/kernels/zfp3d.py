@@ -2,16 +2,22 @@
 + exact integer lifting transform + negabinary + per-group significance
 (TPU-ZFP stages 1-3 + header derivation, the compression hot loop).
 
-Tiling: 256 4x4x4 blocks per grid step -> in tile (256, 4, 4, 4) f32
-(64 KiB) and out tiles (256, 64) u32 + (256, 10) i32 headers. All VPU work:
+Tiling: 256 4x4x4 blocks per grid step, coefficient-major (blocks on the
+lanes): in tile (64, 256) f32 (64 KiB), out tiles (64, 256) u32 + (1, 256)
+emax + (10, 256) group tops. All VPU work:
 
 * the block exponent uses the IEEE bit trick ((bits >> 23) & 0xff) instead
   of frexp — branch-free and exactly what the CUDA kernel does;
 * 2^(Q - e) is constructed directly in exponent bits (exact powers of two,
   no transcendental);
-* the lifting shift-add sequence vectorizes over the 256-block axis;
-* group significance = 10 static masked maxes (groups are a compile-time
+* the lifting shift-add sequence vectorizes over the 256-block lanes, each
+  axis's four coefficient quarters gathered by a static row permutation;
+* group significance = 10 static row-run maxes (groups are a compile-time
   property of the 4x4x4 sequency layout).
+
+Stages 1-3 are ``repro.core.zfp._transform_cm`` itself — the one shared
+implementation of the bit-exact arithmetic that the core coder and the
+fused encode kernel (``repro.kernels.zfp_fused``) also run.
 
 This kernel backs the ``xla`` ZFP path: the embedded coding runs outside in
 the word-level jnp coder (``repro.core.zfp.encode_words``), which costs one
@@ -28,116 +34,47 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import zfp as zfp_core
 from repro.kernels import default_interpret
 
 BLOCKS_PER_TILE = 256
-Q = zfp_core.Q
-
-
-def _fwd_lift_axis(v: jax.Array, axis: int) -> jax.Array:
-    idx = [slice(None)] * v.ndim
-    def take(i):
-        s = list(idx)
-        s[axis] = i
-        return v[tuple(s)]
-    x, y, z, w = take(0), take(1), take(2), take(3)
-    x = x + w
-    x = x >> 1
-    w = w - x
-    z = z + y
-    z = z >> 1
-    y = y - z
-    x = x + z
-    x = x >> 1
-    z = z - x
-    w = w + y
-    w = w >> 1
-    y = y - w
-    w = w + (y >> 1)
-    y = y - (w >> 1)
-    return jnp.stack([x, y, z, w], axis=axis)
-
-
-def _bitlength(u: jax.Array) -> jax.Array:
-    w = jnp.zeros(u.shape, jnp.int32)
-    v = u
-    for s in (16, 8, 4, 2, 1):
-        m = v >= jnp.uint32(1 << s)
-        w = w + m.astype(jnp.int32) * s
-        v = jnp.where(m, v >> s, v)
-    return w + (v > 0).astype(jnp.int32)
-
-
-def block_float_negabinary(blocks: jax.Array):
-    """Stages 1-3 on a (T, 4, 4, 4) f32 tile: -> (u index-order uint32[T, 64],
-    e i32[T], nonzero bool[T]).  One shared implementation of the bit-exact
-    arithmetic (IEEE exponent-bit exponent/scale, lift, negabinary) traced by
-    both this transform kernel and the fused encode kernel
-    (``repro.kernels.zfp_fused``) — the cross-path byte-identity contract
-    hangs on these stages never diverging."""
-    b = blocks.astype(jnp.float32)  # (T, 4, 4, 4)
-    maxabs = jnp.max(jnp.abs(b), axis=(1, 2, 3))  # (T,)
-    bits = jax.lax.bitcast_convert_type(maxabs, jnp.uint32)
-    e_biased = ((bits >> 23) & jnp.uint32(0xFF)).astype(jnp.int32)
-    e = jnp.clip(e_biased - 126, -100, 127)  # frexp convention: maxabs < 2^e
-    nonzero = maxabs > 0.0
-    # scale = 2^(Q - e), built in exponent bits (exact, branch-free)
-    scale = jax.lax.bitcast_convert_type(
-        ((Q - e + 127).astype(jnp.uint32) << 23), jnp.float32)
-    ints = jnp.round(b * scale[:, None, None, None]).astype(jnp.int32)
-    coef = ints
-    for axis in (3, 2, 1):
-        coef = _fwd_lift_axis(coef, axis)
-    # negabinary, inlined (no captured module constants in a pallas body)
-    nbmask = jnp.uint32(0xAAAAAAAA)
-    u = (coef.reshape(-1, 64).astype(jnp.uint32) + nbmask) ^ nbmask
-    return u, e, nonzero
 
 
 def _zfp_kernel(blocks_ref, u_ref, emax_ref, gtops_ref):
-    u, e, nonzero = block_float_negabinary(blocks_ref[...])
-    lens = _bitlength(u)
-    # sequency group of column c (x-fastest index order) from iota arithmetic:
-    # deg = (c & 3) + ((c >> 2) & 3) + (c >> 4)
-    col = jax.lax.broadcasted_iota(jnp.int32, lens.shape, 1)
-    deg = (col & 3) + ((col >> 2) & 3) + (col >> 4)
-    for g in range(zfp_core.N_GROUPS):
-        sel = jnp.where(deg == g, lens, 0)
-        gtops_ref[:, g] = jnp.max(sel, axis=1) * nonzero.astype(jnp.int32)
+    u, emax, gtops = zfp_core._transform_cm(blocks_ref[...], zfp_core._IDENTITY)
     u_ref[...] = u
-    emax_ref[...] = jnp.where(nonzero, e + 128, 0).astype(jnp.int32)
+    emax_ref[...] = emax
+    gtops_ref[...] = gtops
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def zfp3d_transform(blocks: jax.Array, interpret: bool | None = None):
-    """(NB, 4, 4, 4) f32 -> (u32 negabinary coefs [index order], emax i32,
-    per-group top planes i32). NB must be a BLOCKS_PER_TILE multiple.
-
-    ``interpret=None`` resolves to interpret-only-off-TPU, so the kernel
-    path is compiled where it matters and emulated elsewhere."""
-    interpret = default_interpret(interpret)
-    nb = blocks.shape[0]
+def zfp3d_transform_cm(blocks: jax.Array, interpret: bool | None = None):
+    """Coefficient-major stages 1-3: f32 (64, NB) index-order blocks ->
+    (u32 negabinary coefs (64, NB) [index order], emax i32 (1, NB), group
+    top planes i32 (10, NB)).  NB must be a BLOCKS_PER_TILE multiple."""
+    nb = blocks.shape[1]
     assert nb % BLOCKS_PER_TILE == 0, "pad block count first (ops.py)"
-    grid = (nb // BLOCKS_PER_TILE,)
     t = BLOCKS_PER_TILE
-    u, emax, gtops = pl.pallas_call(
+    lanes = lambda rows: pl.BlockSpec((rows, t), lambda i: (0, i))
+    return pl.pallas_call(
         _zfp_kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((nb, 64), jnp.uint32),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
-            jax.ShapeDtypeStruct((nb, zfp_core.N_GROUPS), jnp.int32),
+            jax.ShapeDtypeStruct((64, nb), jnp.uint32),
+            jax.ShapeDtypeStruct((1, nb), jnp.int32),
+            jax.ShapeDtypeStruct((zfp_core.N_GROUPS, nb), jnp.int32),
         ),
-        grid=grid,
-        in_specs=[pl.BlockSpec((t, 4, 4, 4), lambda i: (i, 0, 0, 0))],
-        out_specs=(
-            pl.BlockSpec((t, 64), lambda i: (i, 0)),
-            pl.BlockSpec((t,), lambda i: (i,)),
-            pl.BlockSpec((t, zfp_core.N_GROUPS), lambda i: (i, 0)),
-        ),
-        interpret=interpret,
+        grid=(nb // t,),
+        in_specs=[lanes(64)],
+        out_specs=(lanes(64), lanes(1), lanes(zfp_core.N_GROUPS)),
+        interpret=default_interpret(interpret),
     )(blocks)
-    return u, emax, gtops
+
+
+def zfp3d_transform(blocks: jax.Array, interpret: bool | None = None):
+    """(NB, 4, 4, 4) f32 -> (u32 negabinary coefs [NB, 64] index order,
+    emax i32[NB], per-group top planes i32[NB, 10]): the block-major view
+    of :func:`zfp3d_transform_cm`."""
+    u, emax, gtops = zfp3d_transform_cm(blocks.reshape(-1, 64).T, interpret=interpret)
+    return u.T, emax[0], gtops.T

@@ -23,19 +23,27 @@ leaves the chip:
 paths; see DESIGN.md §3.)
 
 The coder body is *the same code* as the XLA path: the kernel calls
-``zfp_core._encode_words_impl`` / ``_extract_coeffs`` — pure elementwise,
-slice and 32x32-bit-transpose jnp that Pallas traces into the kernel — so
-the three paths (core / xla / fused) emit byte-identical streams by
+``zfp_core._transform_cm`` / ``_encode_words_cm`` / ``_extract_coeffs`` /
+``_inverse_cm`` — elementwise, static row slice/permutation and row-roll
+jnp in the coefficient-major layout (coefficients and bit planes on
+sublanes, blocks on lanes) that Pallas traces into the kernel — so the
+three paths (core / xla / fused) emit byte-identical streams by
 construction.  The only formulation difference is the decode word fetch:
-the XLA path gathers each plane's 3 stream words from the flat buffer,
+the XLA path gathers each plane's 3 stream words along the word axis,
 while the kernel (no dynamic gathers on the VPU) selects them with a
 one-hot masked OR over the block's ``wpb`` words — ``wpb`` is static
 (``ceil((rate*64 - 58) / 32)`` = ``2*rate - 1`` words per block, the 58-bit
 header living outside the word array), so this is an unrolled
-O(words-per-block) loop, mirroring ``sz_fused._unpack_blocks``.
+O(words-per-block) loop.
 
-The kernels TARGET TPU; this container validates them in interpret mode
-(no TPU), which is how the byte-identity tests run.
+Kernel operands are coefficient-major too: blocks (64, NB) f32, words
+(wpb, NB) u32, emax (1, NB) i32, gtops (10, NB) i32 — lane-dense, with
+``BLOCKS_PER_TILE`` blocks on the lanes of each grid step.  The
+``ZFPCompressed`` stream format stays block-major; the wrappers transpose.
+
+The kernels compile for TPU (``tests/test_tpu_compile.py`` compiles each for
+a v5e); elsewhere they run in Pallas interpret mode, which is how the
+byte-identity tests run.
 """
 
 from __future__ import annotations
@@ -47,67 +55,101 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import zfp as zfp_core
-from repro.kernels import default_interpret as _default_interpret
-from repro.kernels import zfp3d as _zfp3d
+from repro.kernels import default_interpret
 
-BLOCKS_PER_TILE = 256  # matches zfp3d; largest live tile array is (256, 64) u32
+BLOCKS_PER_TILE = 256  # blocks on the lanes of one grid step
 N_GROUPS = zfp_core.N_GROUPS
 
 
-def _transform_tile(blocks: jax.Array):
-    """Stages 1-3 on a (T, 4, 4, 4) f32 tile -> (u sequency order, emax i32,
-    gtops i32): the shared ``zfp3d.block_float_negabinary`` arithmetic
-    followed by the static sequency permutation."""
-    u_idx, e, nonzero = _zfp3d.block_float_negabinary(blocks)
-    # static permutation to sequency order (unit slices — Pallas-safe)
-    u = zfp_core._take_static(u_idx, zfp_core.PERM)
-    lens = zfp_core._bitlength32(u)
-    # In sequency order the groups are contiguous static segments, so the
-    # per-group significance is 10 static slice-maxes.
-    tops = []
-    for g in range(N_GROUPS):
-        s0, sz = int(zfp_core._gstart[g]), int(zfp_core.GROUP_SIZES[g])
-        tops.append(jnp.max(lens[:, s0:s0 + sz], axis=1))
-    gtops = jnp.stack(tops, axis=1) * nonzero.astype(jnp.int32)[:, None]
-    emax = jnp.where(nonzero, e + 128, 0).astype(jnp.int32)
-    return u, emax, gtops
+def _lanes(rows: int):
+    return pl.BlockSpec((rows, BLOCKS_PER_TILE), lambda i: (0, i))
 
 
 def _fused_encode_kernel(blocks_ref, words_ref, emax_ref, gtops_ref, *, rate):
-    u, emax, gtops = _transform_tile(blocks_ref[...])
-    words_ref[...] = zfp_core._encode_words_impl(u, gtops, rate)
+    u, emax, gtops = zfp_core._transform_cm(blocks_ref[...])
+    words_ref[...] = zfp_core._encode_words_cm(u, gtops, rate)
     emax_ref[...] = emax
     gtops_ref[...] = gtops
 
 
 @functools.partial(jax.jit, static_argnames=("rate", "interpret"))
-def fused_compress_blocks(blocks: jax.Array, rate: int,
-                          interpret: bool | None = None):
-    """One fused pass: (NB, 4, 4, 4) f32 blocks -> (words u32[NB, wpb],
-    emax i32[NB], gtops i32[NB, 10]).  NB must be a BLOCKS_PER_TILE
-    multiple (pad in ops.py); coefficients never leave VMEM."""
-    nb = blocks.shape[0]
+def fused_compress_cm(blocks: jax.Array, rate: int, interpret: bool | None = None):
+    """One fused pass on coefficient-major blocks: f32 (64, NB) -> (words
+    u32[wpb, NB], emax i32[1, NB], gtops i32[10, NB]).  NB must be a
+    BLOCKS_PER_TILE multiple (pad in ops.py); coefficients never leave VMEM."""
+    nb = blocks.shape[1]
     assert nb % BLOCKS_PER_TILE == 0, "pad block count first (ops.py)"
     wpb = zfp_core.payload_words(rate)
-    t = BLOCKS_PER_TILE
-    grid = (nb // t,)
-    words, emax, gtops = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_fused_encode_kernel, rate=rate),
         out_shape=(
-            jax.ShapeDtypeStruct((nb, wpb), jnp.uint32),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
-            jax.ShapeDtypeStruct((nb, N_GROUPS), jnp.int32),
+            jax.ShapeDtypeStruct((wpb, nb), jnp.uint32),
+            jax.ShapeDtypeStruct((1, nb), jnp.int32),
+            jax.ShapeDtypeStruct((N_GROUPS, nb), jnp.int32),
         ),
-        grid=grid,
-        in_specs=[pl.BlockSpec((t, 4, 4, 4), lambda i: (i, 0, 0, 0))],
-        out_specs=(
-            pl.BlockSpec((t, wpb), lambda i: (i, 0)),
-            pl.BlockSpec((t,), lambda i: (i,)),
-            pl.BlockSpec((t, N_GROUPS), lambda i: (i, 0)),
-        ),
-        interpret=_default_interpret(interpret),
+        grid=(nb // BLOCKS_PER_TILE,),
+        in_specs=[_lanes(64)],
+        out_specs=(_lanes(wpb), _lanes(1), _lanes(N_GROUPS)),
+        interpret=default_interpret(interpret),
     )(blocks)
-    return words, emax, gtops
+
+
+def _fused_decode_kernel(words_ref, emax_ref, gtops_ref, blocks_ref, *, rate):
+    budget = rate * 64 - zfp_core._HEADER_BITS
+    words = words_ref[...]  # (wpb, T)
+    gtops = gtops_ref[...]
+    OFF, keep = zfp_core._plane_offsets_cm(gtops, budget)
+    w0 = OFF >> 5
+    # One-hot fetch of the 3 words each plane payload spans (no dynamic
+    # gathers on the VPU; wpb is static so the loop unrolls).
+    zero = jnp.uint32(0)
+    g0 = g1 = g2 = jnp.zeros(OFF.shape, jnp.uint32)
+    for k in range(words.shape[0]):
+        wk = words[k:k + 1]
+        g0 = g0 | jnp.where(w0 == k, wk, zero)
+        g1 = g1 | jnp.where(w0 + 1 == k, wk, zero)
+        g2 = g2 | jnp.where(w0 + 2 == k, wk, zero)
+    u = zfp_core._extract_coeffs(g0, g1, g2, OFF, keep, gtops)
+    blocks_ref[...] = zfp_core._inverse_cm(u, emax_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("rate", "interpret"))
+def fused_decompress_cm(words: jax.Array, emax: jax.Array, gtops: jax.Array,
+                        rate: int, interpret: bool | None = None) -> jax.Array:
+    """Inverse fused pass on coefficient-major operands: words u32[wpb, NB],
+    emax i32[1, NB], gtops i32[10, NB] -> f32 blocks (64, NB).  The
+    coefficient planes are reconstructed and inverted entirely in VMEM."""
+    nb = words.shape[1]
+    assert nb % BLOCKS_PER_TILE == 0, "pad block count first (ops.py)"
+    wpb = zfp_core.payload_words(rate)
+    assert words.shape[0] == wpb, f"stream has {words.shape[0]} words/block, rate {rate} needs {wpb}"
+    return pl.pallas_call(
+        functools.partial(_fused_decode_kernel, rate=rate),
+        out_shape=jax.ShapeDtypeStruct((64, nb), jnp.float32),
+        grid=(nb // BLOCKS_PER_TILE,),
+        in_specs=[_lanes(wpb), _lanes(1), _lanes(N_GROUPS)],
+        out_specs=_lanes(64),
+        interpret=default_interpret(interpret),
+    )(words, emax.astype(jnp.int32), gtops.astype(jnp.int32))
+
+
+def fused_compress_blocks(blocks: jax.Array, rate: int,
+                          interpret: bool | None = None):
+    """Block-major wrapper of :func:`fused_compress_cm`: (NB, 4, 4, 4) f32
+    blocks -> (words u32[NB, wpb], emax i32[NB], gtops i32[NB, 10])."""
+    words, emax, gtops = fused_compress_cm(blocks.reshape(-1, 64).T, rate,
+                                           interpret=interpret)
+    return words.T, emax[0], gtops.T
+
+
+def fused_decompress_blocks(words: jax.Array, emax: jax.Array,
+                            gtops: jax.Array, rate: int,
+                            interpret: bool | None = None) -> jax.Array:
+    """Block-major wrapper of :func:`fused_decompress_cm`: stream + headers
+    -> (NB, 4, 4, 4) f32 blocks."""
+    b = fused_decompress_cm(words.T, emax.astype(jnp.int32)[None, :],
+                            gtops.astype(jnp.int32).T, rate, interpret=interpret)
+    return b.T.reshape(-1, 4, 4, 4)
 
 
 def fused_compress_arena(blocks: jax.Array, rate: int,
@@ -134,50 +176,3 @@ def fused_decompress_arena(arena: jax.Array, emax: jax.Array, gtops: jax.Array,
     wpb = zfp_core.payload_words(rate)
     return fused_decompress_blocks(arena.reshape(-1, wpb), emax, gtops, rate,
                                    interpret=interpret)
-
-
-def _fused_decode_kernel(words_ref, emax_ref, gtops_ref, blocks_ref, *, rate):
-    budget = rate * 64 - zfp_core._HEADER_BITS
-    words = words_ref[...]  # (T, wpb)
-    wpb = words.shape[1]
-    gtops = gtops_ref[...].astype(jnp.int32)
-    OFF, keep = zfp_core._plane_offsets(gtops, budget)
-    w0 = OFF >> 5
-    # One-hot fetch of the 3 words each plane payload spans (no dynamic
-    # gathers on the VPU; wpb is static so the loop unrolls).
-    zero = jnp.zeros_like(OFF).astype(jnp.uint32)
-    g0, g1, g2 = zero, zero, zero
-    for j in range(wpb):
-        wj = words[:, j][:, None]
-        g0 = g0 | jnp.where(w0 == j, wj, jnp.uint32(0))
-        g1 = g1 | jnp.where(w0 + 1 == j, wj, jnp.uint32(0))
-        g2 = g2 | jnp.where(w0 + 2 == j, wj, jnp.uint32(0))
-    u = zfp_core._extract_coeffs(g0, g1, g2, OFF, keep, gtops)
-    u_idx = zfp_core._take_static(u, zfp_core.IPERM)
-    blocks_ref[...] = zfp_core._blocks_from_indexed(u_idx, emax_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("rate", "interpret"))
-def fused_decompress_blocks(words: jax.Array, emax: jax.Array,
-                            gtops: jax.Array, rate: int,
-                            interpret: bool | None = None) -> jax.Array:
-    """Inverse fused pass: stream + headers -> (NB, 4, 4, 4) f32 blocks.
-    The coefficient planes are reconstructed and inverted entirely in VMEM."""
-    nb = words.shape[0]
-    assert nb % BLOCKS_PER_TILE == 0, "pad block count first (ops.py)"
-    wpb = zfp_core.payload_words(rate)
-    assert words.shape[1] == wpb, f"stream has {words.shape[1]} words/block, rate {rate} needs {wpb}"
-    t = BLOCKS_PER_TILE
-    grid = (nb // t,)
-    return pl.pallas_call(
-        functools.partial(_fused_decode_kernel, rate=rate),
-        out_shape=jax.ShapeDtypeStruct((nb, 4, 4, 4), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((t, wpb), lambda i: (i, 0)),
-            pl.BlockSpec((t,), lambda i: (i,)),
-            pl.BlockSpec((t, N_GROUPS), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((t, 4, 4, 4), lambda i: (i, 0, 0, 0)),
-        interpret=_default_interpret(interpret),
-    )(words, emax.astype(jnp.int32), gtops.astype(jnp.int32))

@@ -67,8 +67,6 @@ LINEAR_FAMILIES = {"ssm", "hybrid"}
 def _cost_of(lowered) -> dict:
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -197,8 +197,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per program
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)
         n_dev = mesh.devices.size

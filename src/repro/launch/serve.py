@@ -22,6 +22,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.models.spec import init_params, param_count
 from repro.serving.engine import EngineConfig, Request, ServingEngine
@@ -59,6 +60,7 @@ def main(argv=None) -> int:
     ap.add_argument("--retries", type=int, default=2,
                     help="max re-dispatches after losing a replica")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = registry.get_config(args.arch, smoke=args.smoke)
     model = registry.build_model(cfg)
@@ -87,11 +89,12 @@ def main(argv=None) -> int:
         t0 = time.time()
         done = eng.run_until_drained()
         dt = time.time() - t0
-        if not done.drained:
-            print("WARNING: drain exhausted max_ticks with requests still live")
         toks = sum(len(r.out_tokens) for r in done)
         print(f"{len(done)} requests, {toks} tokens in {dt:.2f}s ({toks/dt:.1f} tok/s); "
               f"KV cache {eng.cache_nbytes()/1e6:.2f} MB")
+        if not done.drained:
+            print("ERROR: drain exhausted max_ticks with requests still live")
+            return 1
         return 0
 
     injector = None
@@ -117,8 +120,6 @@ def main(argv=None) -> int:
     t0 = time.time()
     done = router.run_until_drained()
     dt = time.time() - t0
-    if not done.drained:
-        print("WARNING: router drain exhausted max_ticks with work unresolved")
     toks = sum(len(r.tokens) for r in done)
     shed = done.shed_requests
     print(f"{len(done)} requests: {len(done.completed)} completed, "
@@ -129,6 +130,14 @@ def main(argv=None) -> int:
     if injector:
         fired = ", ".join(f"r{r}t{t}:{k}" for r, t, k in injector.log) or "none"
         print(f"faults fired: {fired}")
+    if not done.drained:
+        print("ERROR: router drain exhausted max_ticks with work unresolved")
+        return 1
+    if shed and injector is None:
+        # with no fault drill armed nothing should fail: a shed request
+        # means every replica faulted (e.g. a kernel that cannot compile)
+        print(f"ERROR: {len(shed)} request(s) shed with no fault drill armed")
+        return 1
     return 0
 
 

@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.checkpoint.manager import CheckpointManager, CodecPolicy
 from repro.configs import registry
 from repro.data.tokens import DataConfig, TokenPipeline
@@ -92,21 +93,20 @@ def build_insitu_hook(mesh, out_dir: str, eb: float, min_bytes: int = 1 << 20,
     def _legacy_compress(key, leaf, fields) -> None:
         if key not in compiled:
             try:
-                fn = jax.jit(lambda a, _s=_spec(leaf): insitu.sharded_compress(
-                    a, "sz", mesh, _s, eb=eb))
-                stream = fn(leaf)  # validation errors surface at trace
-                compiled[key] = fn
+                insitu.check_eligible(leaf, "sz", mesh, _spec(leaf))
             except (NotImplementedError, ValueError) as e:
                 # composed-axis / non-divisible / oversized leaves — say so
-                # once instead of silently shrinking the snapshot
+                # once instead of silently shrinking the snapshot.  Only the
+                # eligibility checks are caught: a compile error below
+                # propagates and fails the run.
                 print(f"  in-situ snapshot: skipping {key}: {e}")
                 compiled[key] = None
                 return
-        elif compiled[key] is None:
+            compiled[key] = jax.jit(lambda a, _s=_spec(leaf): insitu.sharded_compress(
+                a, "sz", mesh, _s, eb=eb))
+        if compiled[key] is None:
             return
-        else:
-            stream = compiled[key](leaf)
-        fields[key] = insitu.to_host(stream)
+        fields[key] = insitu.to_host(compiled[key](leaf))
 
     def _replan(named) -> None:
         entries = []
@@ -293,6 +293,7 @@ def main(argv=None) -> int:
                          "JSON (trace_*.json, one track per thread) into "
                          "--metrics-dir (or --ckpt-dir)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     obs_out = _setup_obs(args)
     if args.supervise:

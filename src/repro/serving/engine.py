@@ -223,6 +223,13 @@ class ServingEngine:
         self._c_completed = obs_metrics.counter("serving.completed")
         self._c_deferred = obs_metrics.counter("serving.admission_deferred")
 
+    def lower_decode_step(self):
+        """Lower (not run) the jitted decode step this engine dispatches,
+        at its current cache and slot state — to inspect the program (e.g.
+        that the fused KV-attention kernel is in it)."""
+        tokens = jnp.zeros(self.cfg.batch_slots, jnp.int32)
+        return self._step.lower(self.params, self.cache, tokens, self._index_arg())
+
     # -------------------------------------------------------- lifecycle --
     def submit(self, req: Request) -> None:
         if not req.prompt:

@@ -48,6 +48,38 @@ class TestLorenzo3D:
         want = ref.lorenzo3d_reconstruct_ref(d, ebi)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=0)
 
+    @pytest.mark.parametrize("axis", [0, 1, 2])
+    def test_prefix_sum_int32_edges(self, axis):
+        """The in-kernel log-step prefix sum == jnp.cumsum bit for bit on a
+        full tile, including int32 wraparound: values at and near +-2^31
+        and spikes on the tile's first and last planes/rows/lanes."""
+        from repro.kernels.lorenzo3d import prefix_sum
+
+        rng = np.random.default_rng(axis)
+        edge = np.array([2**31 - 1, -(2**31), 2**31 - 2, -(2**31) + 1, 1, -1, 0],
+                        np.int64)
+        d = rng.choice(edge, size=TILE).astype(np.int32)
+        idx = [slice(None)] * 3
+        for pos in (0, TILE[axis] - 1):
+            idx[axis] = pos
+            d[tuple(idx)] = np.int32(2**31 - 1)
+        got = prefix_sum(jnp.asarray(d), axis)
+        want = jnp.cumsum(jnp.asarray(d), axis=axis, dtype=jnp.int32)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_reconstruct_matches_ref_at_int32_extremes(self):
+        """The reconstruct kernel's 3-fold prefix sum wraps exactly like the
+        reference's cumsum, tile edges included."""
+        rng = np.random.default_rng(12)
+        shape = (16, 64, 256)  # 2 x 1 x 2 tiles: seams on two axes
+        d = rng.integers(-(2**31), 2**31, size=shape, dtype=np.int64).astype(np.int32)
+        d[7:9], d[:, 63:65] = 2**31 - 1, -(2**31)
+        d[..., 127:129] = 2**31 - 1
+        ebi = jnp.float32(0.5)
+        got = lorenzo3d_reconstruct(jnp.asarray(d), ebi)
+        want = ref.lorenzo3d_reconstruct_ref(jnp.asarray(d), ebi)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
     def test_ops_end_to_end_with_padding(self):
         x = jnp.asarray(_field((10, 70, 130), seed=5))  # non-tile-multiple
         packed, padded, ebi = ops.sz_compress_kernel(x, 1e-2)
