@@ -1,0 +1,7 @@
+"""Share of the decompress spans in which no operation ran on the device (%)."""
+
+from bench.metrics._share import idle_percent
+
+
+def read(ctx):
+    return idle_percent(ctx, "decompress")

@@ -1,0 +1,8 @@
+"""ZFP encode: (raw bytes + stream bytes) over peak HBM bandwidth times the
+device busy time inside the compress spans (%)."""
+
+from bench.metrics._share import hbm_percent
+
+
+def read(ctx):
+    return hbm_percent(ctx, "tpu-zfp", "compress")
