@@ -6,7 +6,7 @@ Sections:
   fig4  rate-distortion curves (PSNR vs bitrate), SZ + ZFP, Nyx + HACC
   fig5  power-spectrum pk-ratio gate at the best-fit configs
   fig6  FoF halo mass-function / count-ratio gate
-  fig7-10  throughput: stage breakdown, modeled TPU kernels, rate scaling
+  fig7-10  throughput: stage breakdown, rate scaling
   serving  continuous-batching load generator: Poisson arrivals, none vs
         blockfloat8 KV, equal-pool-bytes concurrency (>=1.8x gate)
   vd    §V-D guideline end-to-end (best-fit configs + overall CR)
@@ -156,7 +156,6 @@ def run_throughput(n: int, vs_bitrate_n: int, smoke: bool = False,
         "n": n,
         "measured_breakdown": throughput.measured_breakdown(n=n),
         "zfp_stage_breakdown": throughput.zfp_stage_breakdown(n=n),
-        "modeled_tpu": throughput.modeled_tpu_kernel_throughput(),
         "packer": throughput.packer_microbench(n=1 << 18 if smoke else 1 << 22),
         "dist": throughput.dist_wire_bytes(n=1 << 18 if smoke else 1 << 22),
         "insitu": throughput.insitu_snapshot(n=n),
@@ -228,13 +227,11 @@ def main(argv=None) -> int:
     t0 = time.time()
 
     if smoke:
-        _section("Throughput smoke (measured CPU + modeled TPU)")
+        _section("Throughput smoke (measured CPU)")
         record = run_throughput(n=n, vs_bitrate_n=0, smoke=True)
         for r in record["measured_breakdown"]:
             print(r)
         for r in record["zfp_stage_breakdown"]:
-            print(r)
-        for r in record["modeled_tpu"]:
             print(r)
         print(record["packer"])
         print("dist:", record["dist"])
@@ -278,14 +275,12 @@ def main(argv=None) -> int:
     for r in hrows:
         print(",".join(str(r[c]) for c in cols))
 
-    _section("Figs 7-10 — throughput (measured CPU + modeled TPU)")
+    _section("Figs 7-10 — throughput (measured CPU)")
     record = run_throughput(n=n, vs_bitrate_n=32 if fast else 48,
                             mode="fast" if fast else "full")
     for r in record["measured_breakdown"]:
         print(r)
     for r in record["zfp_stage_breakdown"]:
-        print(r)
-    for r in record["modeled_tpu"]:
         print(r)
     for r in record["throughput_vs_bitrate"]:
         print(r)

@@ -1,13 +1,8 @@
 """Paper Figs. 7-10 + Table I: (de)compression time breakdown (init /
-kernel / memcpy / free analogue), throughput vs bitrate, and the modeled
-roofline throughput on the target accelerator.
-
-This container has no TPU, so two layers are reported honestly:
-  * measured: wall-clock CPU(+interpret kernel) throughput of our
-    implementation — the "CPU-based compressor" column of the paper's Fig 8;
-  * modeled: HBM-roofline kernel throughput on TPU v5e (819 GB/s) from the
-    kernels' exact byte traffic — the analogue of Fig 9's per-GPU kernel
-    numbers, derived instead of timed (no hardware), clearly labeled.
+kernel / memcpy / free analogue) and throughput vs bitrate, as wall-clock
+CPU(+interpret kernel) throughput of our implementation — the
+"CPU-based compressor" column of the paper's Fig 8.  Device numbers come
+from the on-chip benchmark (``bench/``), not from here.
 """
 
 from __future__ import annotations
@@ -21,7 +16,6 @@ import numpy as np
 from repro.core import sz, zfp
 from repro.data import cosmo
 
-HBM_GBS = 819.0  # TPU v5e
 PCIE_GBS = 16.0  # paper's GPUs: 16-lane PCIe 3.0 (for the memcpy analogue)
 
 
@@ -98,48 +92,6 @@ def zfp_stage_breakdown(n: int = 64, rates=(4, 8)):
             "memcpy_s": comp_bytes / 1e9 / PCIE_GBS,
             "coder_c_mbs": mb / t_ec, "coder_d_mbs": mb / t_dc,
         })
-    return rows
-
-
-def modeled_tpu_kernel_throughput():
-    """Fig 9 analogue (modeled, no hardware): kernel bytes / HBM bandwidth.
-
-    Unfused TPU-SZ (lorenzo3d kernel + separate bitpack call): quantize
-    reads f32 (4B) + writes i32 codes (4B) = 8 B/pt; packing re-reads the
-    codes (4B) and scatter-adds into the stream (~1 B/pt at the paper's
-    ~5 bit/value configs) => ~13 B/pt.
-
-    Fused TPU-SZ (``kernels.sz_fused``, one VMEM pass): read f32 (4B) +
-    write packed words.  The static worst-case block-payload buffer is
-    1 word/code (4 B/pt written); only ~bitrate/8 of it is real payload,
-    and the stream-assembly gather moves ~2 x bitrate/8 more.  At ~5
-    bits/value that is 4 + 0.625 + 1.25 ~= 5.9 B/pt effective (8 B/pt if
-    the worst-case buffer write is charged in full).
-
-    Unfused TPU-ZFP (zfp3d transform kernel + XLA coder): the transform
-    writes the u32 coefficient planes (4 B/pt) which the coder re-reads
-    (4 B/pt) before emitting rate/8 B/pt => ~12 + rate/8 B/pt.
-
-    Fused TPU-ZFP (``kernels.zfp_fused``): read 4B + write rate/8 B +
-    headers => 4 + (rate + 1.4)/8 B/pt — the coefficient planes never
-    leave VMEM (the 4x4x4 carve transpose outside adds 8 B/pt of
-    reshuffle, charged separately as it is shared by all paths).
-    """
-    br = 5.0  # bits/value at the paper's best-fit SZ configs
-    rows = []
-    for name, bytes_per_pt in (
-        ("tpu-sz unfused quantize+lorenzo", 8.0),
-        ("tpu-sz unfused incl. packing", 13.0),
-        ("tpu-sz fused encode (worst-case buffer)", 8.0 + 2 * br / 8.0),
-        ("tpu-sz fused encode (effective)", 4.0 + 3 * br / 8.0),
-        ("tpu-zfp unfused rate=4", 12.0 + 0.5),
-        ("tpu-zfp unfused rate=8", 12.0 + 1.0),
-        ("tpu-zfp fused rate=4", 4.0 + (4 + 1.4) / 8.0),
-        ("tpu-zfp fused rate=8", 4.0 + (8 + 1.4) / 8.0),
-    ):
-        gbs = HBM_GBS / bytes_per_pt * 4.0  # GB of f32 input per second
-        rows.append({"kernel": name, "bytes_per_point": bytes_per_pt,
-                     "modeled_throughput_GBps": gbs})
     return rows
 
 
@@ -431,9 +383,6 @@ def main() -> None:
         print(r)
     print("# Fig7b: tpu-zfp per-stage breakdown (transform vs coder vs memcpy)")
     for r in zfp_stage_breakdown():
-        print(r)
-    print("# Fig9 analogue: modeled TPU v5e kernel throughput (819 GB/s HBM)")
-    for r in modeled_tpu_kernel_throughput():
         print(r)
     print("# Fig10: throughput vs bitrate")
     for r in throughput_vs_bitrate():

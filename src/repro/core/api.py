@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bitpack, sz, transforms, zfp
+from repro.obs import trace as obs_trace
 
 MAX_CHUNK = 1 << 24  # elements per SZ packing call (int32 bit-offset safety)
 
@@ -142,7 +143,8 @@ class SZCompressor:
                               lambda p: sz.compress(p, eb, self.block_size))
         # per-part total_bits are int32; sum on host in int64 (many
         # partitions can exceed 2**31 bits combined)
-        nbits = int(np.sum([np.asarray(c.packed.total_bits, np.int64) for c in comp]))
+        with obs_trace.span("sync.total_bits"):
+            nbits = int(np.sum([np.asarray(c.packed.total_bits, np.int64) for c in comp]))
         return comp, nbits
 
     def _decompress_parts(self, parts_c: list) -> list[jax.Array]:
@@ -153,6 +155,10 @@ class SZCompressor:
 
     def compress(self, x: jax.Array, eb: float | None = None, pw_rel: float | None = None,
                  **_: Any) -> CompressionResult:
+        with obs_trace.span("api.compress", codec=self.name):
+            return self._compress(x, eb, pw_rel)
+
+    def _compress(self, x: jax.Array, eb, pw_rel) -> CompressionResult:
         raw = int(np.prod(x.shape)) * 4
         side_bits = 0
         meta: dict[str, Any] = {"mode": "abs", "eb": eb}
@@ -169,7 +175,8 @@ class SZCompressor:
             from repro.kernels import ops as kops
 
             packed, padded_shape, eb_i = kops.sz_compress_kernel(x, eb)
-            nbits = int(packed.total_bits) + side_bits
+            with obs_trace.span("sync.total_bits"):
+                nbits = int(packed.total_bits) + side_bits
             payload = {"kernel": True, "kpacked": packed, "padded_shape": padded_shape,
                        "eb_i": eb_i, "signs": signs, "shape": x.shape,
                        "orig_len": int(np.prod(x.shape)), "was_1d": False}
@@ -183,6 +190,10 @@ class SZCompressor:
         return CompressionResult(payload, (nbits + 7) // 8, raw, meta)
 
     def decompress(self, r: CompressionResult) -> jax.Array:
+        with obs_trace.span("api.decompress", codec=self.name):
+            return self._decompress(r)
+
+    def _decompress(self, r: CompressionResult) -> jax.Array:
         if r.payload.get("kernel"):
             from repro.kernels import ops as kops
 
@@ -274,6 +285,10 @@ class ZFPCompressor:
                               self.VMAP_ELEM_BUDGET, zfp.decompress)
 
     def compress(self, x: jax.Array, rate: int | None = None, **_: Any) -> CompressionResult:
+        with obs_trace.span("api.compress", codec=self.name):
+            return self._compress(x, rate)
+
+    def _compress(self, x: jax.Array, rate: int | None) -> CompressionResult:
         if rate is None:
             raise ValueError("ZFP requires rate= (bits/value)")
         raw = int(np.prod(x.shape)) * 4  # original count: padding not charged
@@ -288,6 +303,10 @@ class ZFPCompressor:
                                   **shape_meta})
 
     def decompress(self, r: CompressionResult) -> jax.Array:
+        with obs_trace.span("api.decompress", codec=self.name):
+            return self._decompress(r)
+
+    def _decompress(self, r: CompressionResult) -> jax.Array:
         parts = self._decompress_parts(r.payload["parts"])
         orig = r.payload["orig_shape"]
         if r.payload["was_1d"]:

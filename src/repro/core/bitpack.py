@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as obs_trace
+
 # §Perf iteration on the packer itself: per-block max-width is outlier
 # sensitive, so smaller blocks adapt better. Measured on GRF density at a
 # pk-gate-passing bound: 1024 -> 7.40 bpv, 128 -> 5.83, 64 -> 5.48 (header
@@ -133,15 +135,16 @@ def compact_streams(rows: jax.Array, counts: jax.Array, capacity: int):
     worst-case buffers): both were previously hand-rolled copies of the
     same cumsum + masked-gather recipe.
     """
-    counts = counts.astype(jnp.int32)
-    offsets = exclusive_cumsum(counts)
-    used = jnp.sum(counts)
-    i = jnp.arange(capacity, dtype=jnp.int32)
-    r = jnp.searchsorted(offsets, i, side="right").astype(jnp.int32) - 1
-    off = i - offsets[r]
-    valid = (off < counts[r]) & (i < used)
-    vals = rows[r, jnp.clip(off, 0, rows.shape[1] - 1)]
-    words = jnp.where(valid, vals, jnp.uint32(0))
+    with obs_trace.scope("bitpack.compact"):
+        counts = counts.astype(jnp.int32)
+        offsets = exclusive_cumsum(counts)
+        used = jnp.sum(counts)
+        i = jnp.arange(capacity, dtype=jnp.int32)
+        r = jnp.searchsorted(offsets, i, side="right").astype(jnp.int32) - 1
+        off = i - offsets[r]
+        valid = (off < counts[r]) & (i < used)
+        vals = rows[r, jnp.clip(off, 0, rows.shape[1] - 1)]
+        words = jnp.where(valid, vals, jnp.uint32(0))
     return words, offsets, used
 
 
@@ -151,6 +154,12 @@ def pack_codes(codes: jax.Array, block: int = BLOCK) -> PackedCodes:
     n = codes.shape[0]
     if n * 32 >= 2**31:
         raise ValueError(f"pack_codes: n={n} too large for int32 bit offsets; chunk the field")
+    with obs_trace.scope("bitpack.pack"):
+        return _pack_codes(codes, block)
+
+
+def _pack_codes(codes: jax.Array, block: int) -> PackedCodes:
+    n = codes.shape[0]
     n_blocks, padded = _block_layout(n, block)
     u = zigzag(codes)
     u = jnp.pad(u, (0, padded - n))
@@ -192,6 +201,11 @@ def pack_codes(codes: jax.Array, block: int = BLOCK) -> PackedCodes:
 @partial(jax.jit, static_argnames=("block",))
 def unpack_codes(packed: PackedCodes, block: int = BLOCK) -> jax.Array:
     """Inverse of :func:`pack_codes`; returns int32[n]."""
+    with obs_trace.scope("bitpack.unpack"):
+        return _unpack_codes(packed, block)
+
+
+def _unpack_codes(packed: PackedCodes, block: int) -> jax.Array:
     n = packed.n
     n_blocks, padded = _block_layout(n, block)
     width = packed.widths.astype(jnp.int32)
@@ -309,13 +323,15 @@ def packed_nbytes(packed: PackedCodes) -> jax.Array:
 
 def to_storage(packed: PackedCodes) -> dict[str, np.ndarray]:
     """Host-side: slice the worst-case buffer down to the real payload."""
-    bits = int(packed.total_bits)
-    n_words = (bits - int(packed.widths.shape[0]) * _WIDTH_BITS + 31) // 32
-    return {
-        "words": np.asarray(packed.words[:n_words]),
-        "widths": np.asarray(packed.widths),
-        "n": np.asarray(packed.n),
-    }
+    with obs_trace.span("bitpack.to_storage"):
+        with obs_trace.span("sync.total_bits"):
+            bits = int(packed.total_bits)
+        n_words = (bits - int(packed.widths.shape[0]) * _WIDTH_BITS + 31) // 32
+        with obs_trace.span("sync.copy", what="words"):
+            words = np.asarray(packed.words[:n_words])
+        with obs_trace.span("sync.copy", what="widths"):
+            widths = np.asarray(packed.widths)
+        return {"words": words, "widths": widths, "n": np.asarray(packed.n)}
 
 
 def from_storage(words, widths, n: int, total_bits=None) -> PackedCodes:
@@ -324,13 +340,14 @@ def from_storage(words, widths, n: int, total_bits=None) -> PackedCodes:
     the worst-case ``n + 2`` capacity the unpackers expect.  The shared
     rebuild for the checkpoint reader, ``dist.insitu`` and ``core.arena``
     host paths."""
-    words = np.asarray(words, np.uint32)
-    widths = np.asarray(widths, np.uint8)
-    if total_bits is None:
-        total_bits = int(np.sum(widths.astype(np.int64)) * BLOCK
-                         + widths.shape[0] * _WIDTH_BITS)
-    cap = n + 2
-    wfull = np.zeros(cap, np.uint32)
-    wfull[: len(words)] = words
-    return PackedCodes(jnp.asarray(wfull), jnp.asarray(widths),
-                       jnp.int32(total_bits), n)
+    with obs_trace.span("bitpack.from_storage"):
+        words = np.asarray(words, np.uint32)
+        widths = np.asarray(widths, np.uint8)
+        if total_bits is None:
+            total_bits = int(np.sum(widths.astype(np.int64)) * BLOCK
+                             + widths.shape[0] * _WIDTH_BITS)
+        with obs_trace.span("bitpack.extend"):
+            wfull = np.zeros(n + 2, np.uint32)
+            wfull[: len(words)] = words
+        return PackedCodes(jnp.asarray(wfull), jnp.asarray(widths),
+                           jnp.int32(total_bits), n)

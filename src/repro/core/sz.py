@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bitpack
+from repro.obs import trace as obs_trace
 
 
 @partial(jax.tree_util.register_dataclass, data_fields=("packed", "eb"),
@@ -159,15 +160,16 @@ def internal_bound(absmax: jax.Array, eb) -> jax.Array:
 def compress(x: jax.Array, eb, block_size: int | None = None) -> SZCompressed:
     """Error-bounded (ABS mode) compression of a 1-D/2-D/3-D float field."""
     x = x.astype(jnp.float32)
-    eb_i = internal_bound(jnp.max(jnp.abs(x)), eb)
-    q = jnp.round(x / (2.0 * eb_i)).astype(jnp.int32)
-    if block_size is None:
-        delta = lorenzo_residual(q)
-    else:
-        qb, _ = _to_blocks(q, block_size)
-        nd = x.ndim
-        flatb = qb.reshape((-1,) + qb.shape[-nd:])
-        delta = jax.vmap(lorenzo_residual)(flatb).reshape(qb.shape)
+    with obs_trace.scope("sz.predict"):
+        eb_i = internal_bound(jnp.max(jnp.abs(x)), eb)
+        q = jnp.round(x / (2.0 * eb_i)).astype(jnp.int32)
+        if block_size is None:
+            delta = lorenzo_residual(q)
+        else:
+            qb, _ = _to_blocks(q, block_size)
+            nd = x.ndim
+            flatb = qb.reshape((-1,) + qb.shape[-nd:])
+            delta = jax.vmap(lorenzo_residual)(flatb).reshape(qb.shape)
     packed = bitpack.pack_codes(delta.reshape(-1))
     return SZCompressed(packed, eb_i, x.shape, block_size)  # store the bound used
 
@@ -176,19 +178,20 @@ def compress(x: jax.Array, eb, block_size: int | None = None) -> SZCompressed:
 def decompress(c: SZCompressed) -> jax.Array:
     codes = bitpack.unpack_codes(c.packed)
     b = c.block_size
-    if b is None:
-        delta = codes.reshape(c.shape)
-        q = lorenzo_reconstruct(delta)
-    else:
-        nd = len(c.shape)
-        padded_shape = tuple(s + ((-s) % b) for s in c.shape)
-        grid = tuple(s // b for s in padded_shape)
-        blk_shape = grid + (b,) * nd
-        delta = codes.reshape(blk_shape)
-        flatb = delta.reshape((-1,) + (b,) * nd)
-        qb = jax.vmap(lorenzo_reconstruct)(flatb).reshape(blk_shape)
-        q = _from_blocks(qb, padded_shape, c.shape, b)
-    return q.astype(jnp.float32) * (2.0 * c.eb)
+    with obs_trace.scope("sz.reconstruct"):
+        if b is None:
+            delta = codes.reshape(c.shape)
+            q = lorenzo_reconstruct(delta)
+        else:
+            nd = len(c.shape)
+            padded_shape = tuple(s + ((-s) % b) for s in c.shape)
+            grid = tuple(s // b for s in padded_shape)
+            blk_shape = grid + (b,) * nd
+            delta = codes.reshape(blk_shape)
+            flatb = delta.reshape((-1,) + (b,) * nd)
+            qb = jax.vmap(lorenzo_reconstruct)(flatb).reshape(blk_shape)
+            q = _from_blocks(qb, padded_shape, c.shape, b)
+        return q.astype(jnp.float32) * (2.0 * c.eb)
 
 
 def compressed_nbytes(c: SZCompressed) -> jax.Array:

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bitpack
+from repro.obs import trace as obs_trace
 
 Q = 25  # fixed-point fractional bits; transform growth (< 2^3) keeps int32 safe
 _NBMASK_VAL = 0xAAAAAAAA  # python int: jnp scalars are built per-call so the
@@ -237,20 +238,22 @@ def _carve_cm(x: jax.Array) -> jax.Array:
     — 64 strided slices.  (A reshape to ``(..., Z/4, 4)`` + transpose would
     materialize arrays whose minor dimension is 4, which the TPU pads to a
     128-lane tile: 32x the field, more than a chip holds at 512^3.)"""
-    pads = [(0, (-s) % 4) for s in x.shape]
-    xp = jnp.pad(x, pads, mode="edge")
-    return jnp.stack([xp[r // 16::4, (r // 4) % 4::4, r % 4::4].reshape(-1)
-                      for r in range(64)])
+    with obs_trace.span("zfp.carve"):
+        pads = [(0, (-s) % 4) for s in x.shape]
+        xp = jnp.pad(x, pads, mode="edge")
+        return jnp.stack([xp[r // 16::4, (r // 4) % 4::4, r % 4::4].reshape(-1)
+                          for r in range(64)])
 
 
 def _uncarve_cm(b: jax.Array, shape) -> jax.Array:
     """Inverse of :func:`_carve_cm` (strided stores; crops the edge padding)."""
-    padded = tuple(s + ((-s) % 4) for s in shape)
-    grid = tuple(s // 4 for s in padded)
-    xp = jnp.zeros(padded, b.dtype)
-    for r in range(64):
-        xp = xp.at[r // 16::4, (r // 4) % 4::4, r % 4::4].set(b[r].reshape(grid))
-    return xp[tuple(slice(0, s) for s in shape)]
+    with obs_trace.span("zfp.uncarve"):
+        padded = tuple(s + ((-s) % 4) for s in shape)
+        grid = tuple(s // 4 for s in padded)
+        xp = jnp.zeros(padded, b.dtype)
+        for r in range(64):
+            xp = xp.at[r // 16::4, (r // 4) % 4::4, r % 4::4].set(b[r].reshape(grid))
+        return xp[tuple(slice(0, s) for s in shape)]
 
 
 _IDENTITY = tuple(range(64))

@@ -103,5 +103,6 @@ def kvc_decode_attention(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, d), jnp.float32),
         ],
+        name="kvc_decode_attention",
         interpret=default_interpret(interpret),
     )(idx, q, k_codes, k_scale, v_codes, v_scale)

@@ -107,6 +107,7 @@ def lorenzo3d_quantize(x: jax.Array, eb_i: jax.Array,
             pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
         ],
         out_specs=pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
+        name="lorenzo3d_quantize",
         interpret=default_interpret(interpret),
     )(eb_arr, x)
 
@@ -136,5 +137,6 @@ def lorenzo3d_reconstruct(delta: jax.Array, eb_i: jax.Array,
             pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
         ],
         out_specs=pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
+        name="lorenzo3d_reconstruct",
         interpret=default_interpret(interpret),
     )(eb_arr, delta)

@@ -63,6 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import bitpack
 from repro.kernels import default_interpret
 from repro.kernels import lorenzo3d as _lor
+from repro.obs import trace as obs_trace
 
 TILE = _lor.TILE  # (8, 64, 128)
 CODES_PER_TILE = TILE[0] * TILE[1] * TILE[2]  # 65536
@@ -275,6 +276,7 @@ def _fused_encode(x: jax.Array, eb_i: jax.Array, interpret: bool | None = None):
             pl.BlockSpec((ROWS, 128), lambda i, j, k: (tidx(i, j, k), 0)),
             pl.BlockSpec((1, 2, ROWS), lambda i, j, k: (tidx(i, j, k), 0, 0)),
         ),
+        name="sz_fused_encode",
         interpret=default_interpret(interpret),
     )(eb_arr, x)
     return (words.reshape(-1, WORDS_PER_BLOCK), _widths_from_rows(widths))
@@ -335,6 +337,7 @@ def _fused_encode_batched(x: jax.Array, eb_i: jax.Array, interpret: bool | None 
             pl.BlockSpec((ROWS, 128), lambda b, i, j, k: (tidx(b, i, j, k), 0)),
             pl.BlockSpec((1, 2, ROWS), lambda b, i, j, k: (tidx(b, i, j, k), 0, 0)),
         ),
+        name="sz_fused_encode_batched",
         interpret=default_interpret(interpret),
     )(eb_arr, x)
     return (words.reshape(-1, WORDS_PER_BLOCK), _widths_from_rows(widths))
@@ -398,14 +401,19 @@ def _disassemble_stream(packed: bitpack.PackedCodes) -> tuple[jax.Array, jax.Arr
     """Dense global stream -> per-block payload rows (inverse of
     :func:`_assemble_stream`; one XLA gather)."""
     width = packed.widths.astype(jnp.int32)
-    wcount = 2 * width
-    base = bitpack.exclusive_cumsum(wcount)
-    j = jnp.arange(WORDS_PER_BLOCK, dtype=jnp.int32)
-    idx = base[:, None] + j[None, :]
-    cap = packed.words.shape[0]
-    vals = packed.words[jnp.clip(idx, 0, cap - 1)]
-    block_words = jnp.where(j[None, :] < wcount[:, None], vals, jnp.uint32(0))
-    return block_words, width
+    return _gather_blocks(packed.words, width), width
+
+
+def _gather_blocks(words: jax.Array, width: jax.Array) -> jax.Array:
+    """Block payload rows (uint32[n_blocks, WORDS_PER_BLOCK], zero beyond
+    ``2 * width`` words) gathered out of back-to-back dense ``words``."""
+    with obs_trace.scope("sz_fused.disassemble"):
+        wcount = 2 * width
+        base = bitpack.exclusive_cumsum(wcount)
+        j = jnp.arange(WORDS_PER_BLOCK, dtype=jnp.int32)
+        idx = base[:, None] + j[None, :]
+        vals = words[jnp.clip(idx, 0, words.shape[0] - 1)]
+        return jnp.where(j[None, :] < wcount[:, None], vals, jnp.uint32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("padded_shape", "interpret"))
@@ -429,6 +437,7 @@ def fused_decompress(packed: bitpack.PackedCodes, padded_shape: tuple[int, ...],
             pl.BlockSpec((1, 2, ROWS), lambda i, j, k, gy=gy, gx=gx: (i * gy * gx + j * gx + k, 0, 0)),
         ],
         out_specs=pl.BlockSpec(TILE, lambda i, j, k: (i, j, k)),
+        name="sz_fused_decode",
         interpret=default_interpret(interpret),
     )(eb_arr, words_c, widths_c)
 
@@ -448,13 +457,7 @@ def fused_decompress_batched(arena: jax.Array, widths: jax.Array,
     gz, gy, gx = _grid(padded_shape)
     n_tiles = gz * gy * gx
     width = widths.reshape(-1).astype(jnp.int32)  # [B * blocks_per_row]
-    wcount = 2 * width
-    base = bitpack.exclusive_cumsum(wcount)
-    j = jnp.arange(WORDS_PER_BLOCK, dtype=jnp.int32)
-    idx = base[:, None] + j[None, :]
-    cap = arena.shape[0]
-    vals = arena[jnp.clip(idx, 0, cap - 1)]
-    block_words = jnp.where(j[None, :] < wcount[:, None], vals, jnp.uint32(0))
+    block_words = _gather_blocks(arena, width)
 
     words_c = block_words.reshape(bsz * n_tiles * ROWS, 128)
     widths_c = _widths_to_rows(width)
@@ -470,5 +473,6 @@ def fused_decompress_batched(arena: jax.Array, widths: jax.Array,
             pl.BlockSpec((1, 2, ROWS), lambda b, i, j, k: (tidx(b, i, j, k), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1,) + TILE, lambda b, i, j, k: (b, i, j, k)),
+        name="sz_fused_decode_batched",
         interpret=default_interpret(interpret),
     )(eb_arr, words_c, widths_c)

@@ -68,6 +68,7 @@ def zfp3d_transform_cm(blocks: jax.Array, interpret: bool | None = None):
         grid=(nb // t,),
         in_specs=[lanes(64)],
         out_specs=(lanes(64), lanes(1), lanes(zfp_core.N_GROUPS)),
+        name="zfp3d_transform",
         interpret=default_interpret(interpret),
     )(blocks)
 

@@ -90,6 +90,7 @@ def fused_compress_cm(blocks: jax.Array, rate: int, interpret: bool | None = Non
         grid=(nb // BLOCKS_PER_TILE,),
         in_specs=[_lanes(64)],
         out_specs=(_lanes(wpb), _lanes(1), _lanes(N_GROUPS)),
+        name="zfp_fused_encode",
         interpret=default_interpret(interpret),
     )(blocks)
 
@@ -129,6 +130,7 @@ def fused_decompress_cm(words: jax.Array, emax: jax.Array, gtops: jax.Array,
         grid=(nb // BLOCKS_PER_TILE,),
         in_specs=[_lanes(wpb), _lanes(1), _lanes(N_GROUPS)],
         out_specs=_lanes(64),
+        name="zfp_fused_decode",
         interpret=default_interpret(interpret),
     )(words, emax.astype(jnp.int32), gtops.astype(jnp.int32))
 

@@ -8,7 +8,8 @@ path is a single attribute check per call.
   * :mod:`repro.obs.metrics`     — counters / gauges / ring-buffer
     histograms in a process-global registry, JSONL export + summary();
   * :mod:`repro.obs.trace`       — nested span timers, Chrome-trace JSON,
-    one track per thread;
+    one track per thread, forwarded as ``repro/<name>`` into a recording
+    JAX profiler's trace; the names of the codec path's device stages;
   * :mod:`repro.obs.observatory` — per-snapshot per-bucket compression
     records beside the manifest, run-level rate-quality trajectory.
 """

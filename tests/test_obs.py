@@ -210,7 +210,6 @@ class TestTrace:
         t = threading.Thread(target=worker, name="bg-thread")
         t.start()
         t.join()
-        tr.instant("marker", note="hi")
         doc = json.loads(tr.export(tmp_path / "t.json").read_text())
         _validate_chrome_trace(doc)
         by_name = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
