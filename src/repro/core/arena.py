@@ -24,8 +24,9 @@ terms:
      ``arena-szk``.  It emits the tile-blocked stream, so it can never
      serve this flat path.);
   3. **one scan, one sync**: every row's variable-length words compact into
-     one contiguous uint32 arena with a single device-side exclusive scan
-     over per-row word counts (``bitpack.compact_streams``).  Per-leaf
+     one contiguous uint32 arena on the device: an exclusive scan over
+     per-row word counts, then dense log-step shifts
+     (``bitpack.compact_streams``).  Per-leaf
      ``(offset, used)`` descriptors live in a small sidecar array; the only
      host sync per snapshot is one ``used_total`` readback followed by one
      D2H copy of the arena slice.

@@ -17,9 +17,10 @@ This module fuses dual-quantization + 3-D Lorenzo residual + zigzag +
 per-block width computation + word-level packing into **one VMEM tile
 pass**: the int32 residuals never exist in HBM.  Per (8, 64, 128) tile the
 kernel emits 1024 width headers and the packed payload words of the tile's
-1024 64-code blocks; a cheap XLA gather then concatenates the per-block
-payloads into the dense global stream (block payloads are word-aligned
-because ``BLOCK * w = 64w`` bits is always a whole number of uint32 words):
+1024 64-code blocks; :func:`bitpack.compact_streams` then concatenates the
+per-block payloads into the dense global stream by log-step shifts (block
+payloads are word-aligned because ``BLOCK * w = 64w`` bits is always a
+whole number of uint32 words):
 
   =============================  =================================
   stage                          HBM traffic per point
@@ -27,11 +28,13 @@ because ``BLOCK * w = 64w`` bits is always a whole number of uint32 words):
   fused kernel                   read f32 4 B + write words 4 B
                                  (worst-case static buffer; real
                                  payload is ~bitrate/8 B)
-  stream assembly (XLA gather)   ~2 x bitrate/8 B
+  stream assembly                ~24 B per pass: word and
+  (log2(n) dense shift passes)   displacement read twice (here and
+                                 shifted) and written once
   -----------------------------  ---------------------------------
-  total                          ~9 B/pt worst case, ~5.9 B/pt
-                                 effective at the paper's ~5
-                                 bit/value configs (vs ~13 unfused)
+  total                          ~8 B/pt kernel + ~24 x log2(n)
+                                 B/pt assembly (~580 B/pt at 256^3,
+                                 all of it streaming at HBM speed)
   =============================  =================================
 
 Bitstream layout: identical to ``bitpack.pack_codes`` applied to the
@@ -288,7 +291,8 @@ def _assemble_stream(block_words: jax.Array, width: jax.Array, n: int) -> bitpac
     Produces a ``PackedCodes`` byte-identical to ``bitpack.pack_codes`` on
     the tile-major flat residuals: block payloads are word-aligned, so the
     dense stream is one :func:`bitpack.compact_streams` call (exclusive
-    scan of per-block word counts + one gather — no bit arithmetic).
+    scan of per-block word counts, then dense log-step shifts — no gather,
+    no bit arithmetic).
     """
     # capacity n + 2 matches pack_codes' worst-case buffer exactly
     words, _, _ = bitpack.compact_streams(block_words, 2 * width, n + 2)
@@ -351,8 +355,8 @@ def fused_compress_batched(x: jax.Array, eb_i: jax.Array, interpret: bool | None
     Returns ``(arena, widths, offsets, counts, total_bits, used)`` with
     ``arena[offsets[b] : offsets[b] + counts[b]]`` **byte-identical** to
     ``fused_compress(x[b], eb_i[b])``'s true payload (``to_storage``
-    words) — all rows' tiles run under one batched grid and compact with a
-    single device-side exclusive scan (:func:`bitpack.compact_streams`);
+    words) — all rows' tiles run under one batched grid and compact in
+    the same device program (:func:`bitpack.compact_streams`);
     nothing about the layout needs a per-row host round-trip.
     """
     bsz = x.shape[0]

@@ -13,6 +13,8 @@ format (one ``arena_iNNNNN_sNNN.bin`` per shard + descriptor index,
 legacy per-leaf format still restorable).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,6 +57,61 @@ class TestCompaction:
             rows, jnp.asarray([0, 2, 0]), 6)
         np.testing.assert_array_equal(np.asarray(words), [9, 9, 0, 0, 0, 0])
         assert int(used) == 2
+
+    @staticmethod
+    def _case(name):
+        """(rows, counts, capacity) of one named compaction case."""
+        rng = np.random.default_rng(sum(map(ord, name)))
+        n_rows, width, garbage = 300, 64, False
+        if name == "single_row":
+            n_rows = 1
+        elif name == "arena_width":
+            n_rows, width = 37, 66  # core.arena rows are P + 2 words wide
+        elif name.startswith("capacity"):
+            n_rows = 40
+        elif name == "garbage_past_count":
+            garbage = True
+        counts = 2 * rng.integers(0, width // 2 + 1, size=n_rows)
+        if name == "w64_even_zero_runs":
+            counts[10:60] = 0
+            counts[rng.random(n_rows) < 0.3] = 0
+            counts[-20:] = 0
+        elif name == "top_shift_bit":
+            # every row but the last is empty: the last moves left by
+            # (R - 1) * W = 2**14, the top bit of the pass range
+            n_rows = 257
+            counts = np.zeros(n_rows, np.int64)
+            counts[-1] = width
+        rows = rng.integers(1, 2**32, size=(n_rows, width), dtype=np.uint32)
+        if not garbage:
+            rows[np.arange(width)[None, :] >= counts[:, None]] = 0
+        used = int(counts.sum())
+        capacity = {"capacity_below_rw": n_rows * width - 3,
+                    "capacity_equal_rw": n_rows * width,
+                    "capacity_above_rw": n_rows * width + 9}.get(name, used + 2)
+        return rows, counts.astype(np.int32), capacity
+
+    @pytest.mark.parametrize("name", [
+        "w64_even_zero_runs", "garbage_past_count", "capacity_below_rw",
+        "capacity_equal_rw", "capacity_above_rw", "single_row", "top_shift_bit",
+        "arena_width"])
+    def test_compact_streams_property(self, name):
+        rows, counts, capacity = self._case(name)
+        words, offsets, used = jax.jit(bitpack.compact_streams, static_argnums=2)(
+            jnp.asarray(rows), jnp.asarray(counts), capacity)
+        ref = np.concatenate([rows[r, : counts[r]] for r in range(len(counts))])
+        want = np.zeros(capacity, np.uint32)
+        want[: min(len(ref), capacity)] = ref[:capacity]
+        assert words.shape == (capacity,)
+        np.testing.assert_array_equal(np.asarray(words), want)
+        np.testing.assert_array_equal(np.asarray(offsets), np.cumsum(counts) - counts)
+        assert int(used) == len(ref)
+
+    def test_compact_streams_has_no_gather_or_search(self):
+        jaxpr = jax.make_jaxpr(lambda r, c: bitpack.compact_streams(r, c, 600))(
+            jnp.zeros((9, 64), jnp.uint32), jnp.zeros((9,), jnp.int32))
+        found = re.findall(r"\b(gather|while|sort|scatter[-\w]*)\b", str(jaxpr))
+        assert not found, found
 
     def test_fused_assembler_uses_shared_compaction(self):
         # the dedup: sz_fused._assemble_stream must be byte-identical to
@@ -261,6 +318,52 @@ class TestFusedBatched:
             ref = szf.fused_decompress(szf.fused_compress(x[b], eb[b]),
                                        (8, 64, 128), eb[b])
             np.testing.assert_array_equal(np.asarray(y[b]), np.asarray(ref))
+
+    @staticmethod
+    def _mixed_field():
+        """A (64, 64, 128) field whose tiles span block widths 0 to 32:
+        zeros, a smooth wave, a random walk, noise, and noise whose
+        amplitude doubles every two y rows (at an internal bound of 0.5)."""
+        rng = np.random.default_rng(15)
+        x = np.zeros((64, 64, 128), np.float32)
+        z, y, w = np.ogrid[:8, :64, :128]
+        x[8:16] = np.sin(0.05 * (z + y + w)) * 3
+        x[16:32] = np.cumsum(rng.normal(size=(16, 64, 128)), axis=2) * 20
+        x[32:48] = rng.normal(size=(16, 64, 128)) * 1e4
+        x[48:64] = rng.normal(size=(16, 64, 128)) * 2.0 ** (np.arange(64) / 2)[:, None]
+        return jnp.asarray(x), jnp.float32(0.5)
+
+    def test_fused_stream_byte_identical_across_widths(self):
+        from repro.kernels import sz_fused as szf
+        from repro.kernels.lorenzo3d import lorenzo3d_quantize
+
+        x, eb = self._mixed_field()
+        got = szf.fused_compress(x, eb, interpret=True)
+        ref = bitpack.pack_codes(szf.tile_major_flatten(lorenzo3d_quantize(x, eb)))
+        widths = np.asarray(got.widths)
+        assert widths.min() == 0 and widths.max() == 32
+        np.testing.assert_array_equal(widths, np.asarray(ref.widths))
+        np.testing.assert_array_equal(np.asarray(got.words), np.asarray(ref.words))
+        assert int(got.total_bits) == int(ref.total_bits)
+
+    def test_batched_stream_byte_identical_across_widths(self):
+        from repro.kernels import sz_fused as szf
+
+        x, eb = self._mixed_field()
+        rows = x.reshape(4, 16, 64, 128)  # each row holds two kinds of tile
+        ebs = jnp.full((4,), eb)
+        ar, widths, offs, counts, tb, used = szf.fused_compress_batched(
+            rows, ebs, interpret=True)
+        pos = 0
+        for b in range(4):
+            store = bitpack.to_storage(szf.fused_compress(rows[b], eb, interpret=True))
+            assert int(offs[b]) == pos and int(counts[b]) == len(store["words"])
+            np.testing.assert_array_equal(
+                np.asarray(ar)[pos : pos + int(counts[b])], store["words"])
+            np.testing.assert_array_equal(np.asarray(widths)[b], store["widths"])
+            pos += int(counts[b])
+        assert int(used) == pos
+        assert (np.asarray(ar)[pos:] == 0).all()
 
 
 # --------------------------------------------------------------- ZFP arena --
